@@ -53,7 +53,8 @@ def _sdca(K, y, n_real, lam: float, epochs: int = 20):
     Ky = K * y[None, :]  # K_ij y_j
 
     def coord(i, alpha):
-        f_i = (Ky[i] @ alpha) / (lam * n_real)
+        f_i = jnp.dot(Ky[i], alpha,
+                      precision=jax.lax.Precision.HIGHEST) / (lam * n_real)
         grad = 1.0 - y[i] * f_i
         step = grad * lam * n_real / jnp.maximum(K[i, i], 1e-8)
         new = jnp.clip(alpha[i] + step, 0.0, 1.0)
@@ -63,7 +64,9 @@ def _sdca(K, y, n_real, lam: float, epochs: int = 20):
     def epoch(alpha, _):
         return jax.lax.fori_loop(0, n_pad, coord, alpha), None
 
-    alpha0 = jnp.zeros(n_pad, jnp.float32)
+    # built from y so that under shard_map the carry varies over the
+    # mesh axes exactly as the loop's output does (scan requires it)
+    alpha0 = jnp.zeros_like(y, dtype=jnp.float32)
     alpha, _ = jax.lax.scan(epoch, alpha0, None, length=epochs)
     return alpha
 

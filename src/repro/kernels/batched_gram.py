@@ -14,9 +14,9 @@ Layout (same playbook as rbf_gram.py / ensemble_score.py):
   * the dominant term of ||x1 - x2||^2 is the x1 @ x2^T cross matmul on
     the MXU; squared norms and the exp epilogue run on the VPU while
     the tile is resident in VMEM;
-  * per-device gammas ride in as a (g, 1) array read one scalar per
-    device step; the feature dim streams whole into VMEM (sim feature
-    dims are tens, not thousands).
+  * per-device gammas ride in whole in SMEM as a (g,) array, read one
+    scalar per device step; the feature dim streams whole into VMEM
+    (sim feature dims are tens, not thousands).
 
 The caller is responsible for masking: zero-padded rows of x1/x2 yield
 exp(-gamma * ||x_pad||^2) != 0, exactly as in the unbatched kernel.
@@ -32,6 +32,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_BLOCK_M = 128
@@ -41,11 +42,12 @@ DEFAULT_BLOCK_N = 128
 def _batched_gram_kernel(x1_ref, x2_ref, gamma_ref, o_ref):
     x1 = x1_ref[0].astype(jnp.float32)  # (bm, d)
     x2 = x2_ref[0].astype(jnp.float32)  # (bn, d)
-    g = gamma_ref[0, 0]                 # this device's bandwidth
+    g = gamma_ref[pl.program_id(0)]     # this device's bandwidth (SMEM)
     sq1 = jnp.sum(x1 * x1, axis=1)[:, None]  # VPU
     sq2 = jnp.sum(x2 * x2, axis=1)[None, :]
     cross = jax.lax.dot_general(  # MXU: (bm, d) x (bn, d)^T
-        x1, x2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x1, x2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     d2 = jnp.maximum(sq1 + sq2 - 2.0 * cross, 0.0)
     o_ref[0] = jnp.exp(-g * d2)  # fused epilogue in VMEM
@@ -69,7 +71,7 @@ def batched_rbf_gram_pallas(
     nn = -(-n // bn)
     x1p = jnp.pad(x1.astype(jnp.float32), ((0, 0), (0, nm * bm - m), (0, 0)))
     x2p = jnp.pad(x2.astype(jnp.float32), ((0, 0), (0, nn * bn - n), (0, 0)))
-    gam = gammas.astype(jnp.float32).reshape(g, 1)
+    gam = gammas.astype(jnp.float32).reshape(g)
 
     out = pl.pallas_call(
         _batched_gram_kernel,
@@ -77,10 +79,13 @@ def batched_rbf_gram_pallas(
         in_specs=[
             pl.BlockSpec((1, bm, d), lambda t, i, j: (t, i, 0)),
             pl.BlockSpec((1, bn, d), lambda t, i, j: (t, j, 0)),
-            pl.BlockSpec((1, 1), lambda t, i, j: (t, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda t, i, j: (t, i, j)),
-        out_shape=jax.ShapeDtypeStruct((g, nm * bm, nn * bn), jnp.float32),
+        # under shard_map the Grams vary over the mesh axes the inputs do
+        out_shape=jax.ShapeDtypeStruct(
+            (g, nm * bm, nn * bn), jnp.float32,
+            vma=jax.typeof(x1p).vma | jax.typeof(x2p).vma | jax.typeof(gam).vma),
         interpret=interpret,
     )(x1p, x2p, gam)
     return out[:, :m, :n]

@@ -16,9 +16,12 @@ Layout decisions (same playbook as flash_attention.py):
   * the dominant term of ||x - s||^2 is the x @ s^T cross matmul, which
     runs on the MXU; squared norms, the exp epilogue, and the coef
     matvec run on the VPU while the tile is resident;
-  * per-member gammas ride in as a (k, 1) array read one scalar per
-    member step; zero-padded support rows are annihilated by their zero
-    coefficients, and padded query rows are sliced off on return.
+  * per-member gammas ride in whole in SMEM as a (k,) array, read one
+    scalar per member step; coefs ride in as (k, 1, n) so each (1, bn)
+    tile keeps the TPU's (8, 128) block rule (each of a block's last
+    two dims is a multiple of 8 / 128 or the array's own); zero-padded
+    support rows are annihilated by their zero coefficients, and padded
+    query rows are sliced off on return.
 
 Dispatch policy (TPU vs. CPU oracle, REPRO_PALLAS_INTERPRET) is
 documented once in ``repro/serve/__init__.py``; ``kernels/ops.py``
@@ -49,20 +52,22 @@ def _ensemble_score_kernel(x_ref, sup_ref, coef_ref, gamma_ref, o_ref, acc_scr,
 
     x = x_ref[...].astype(jnp.float32)        # (bq, d)
     s = sup_ref[0].astype(jnp.float32)        # (bn, d)
-    c = coef_ref[0].astype(jnp.float32)       # (bn,)
-    g = gamma_ref[0, 0]                       # member-t bandwidth
+    c = coef_ref[0].astype(jnp.float32)       # (1, bn)
+    g = gamma_ref[t]                          # member-t bandwidth (SMEM)
 
     x2 = jnp.sum(x * x, axis=1)[:, None]      # VPU
     s2 = jnp.sum(s * s, axis=1)[None, :]
     cross = jax.lax.dot_general(              # MXU: (bq, d) x (bn, d)^T
-        x, s, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, s, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     d2 = jnp.maximum(x2 + s2 - 2.0 * cross, 0.0)
     # fused epilogue: exp + coef reduction while the tile is in VMEM.
     # zero-padded support rows contribute exp(..) * 0 via their coef.
-    part = jax.lax.dot_general(               # (bq, bn) x (bn, 1)
-        jnp.exp(-g * d2), c[:, None],
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+    part = jax.lax.dot_general(               # (bq, bn) x (1, bn)^T
+        jnp.exp(-g * d2), c,
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     acc_scr[...] += part * inv_k
 
@@ -90,8 +95,9 @@ def ensemble_score_pallas(
     nn = -(-n_max // bn)
     xp = jnp.pad(x.astype(jnp.float32), ((0, nb * bq - b), (0, 0)))
     supp = jnp.pad(sup.astype(jnp.float32), ((0, 0), (0, nn * bn - n_max), (0, 0)))
-    coefp = jnp.pad(coef.astype(jnp.float32), ((0, 0), (0, nn * bn - n_max)))
-    gam = gammas.astype(jnp.float32).reshape(k, 1)
+    coefp = jnp.pad(coef.astype(jnp.float32),
+                    ((0, 0), (0, nn * bn - n_max))).reshape(k, 1, nn * bn)
+    gam = gammas.astype(jnp.float32).reshape(k)
 
     kernel = functools.partial(
         _ensemble_score_kernel, inv_k=1.0 / float(k), k=k, nn=nn
@@ -102,8 +108,8 @@ def ensemble_score_pallas(
         in_specs=[
             pl.BlockSpec((bq, d), lambda i, t, j: (i, 0)),
             pl.BlockSpec((1, bn, d), lambda i, t, j: (t, j, 0)),
-            pl.BlockSpec((1, bn), lambda i, t, j: (t, j)),
-            pl.BlockSpec((1, 1), lambda i, t, j: (t, 0)),
+            pl.BlockSpec((1, 1, bn), lambda i, t, j: (t, 0, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bq, 1), lambda i, t, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * bq, 1), jnp.float32),

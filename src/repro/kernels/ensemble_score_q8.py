@@ -13,7 +13,9 @@ dispatch per member, losing both the fusion and the compression.
 Layout: identical to ensemble_score.py — grid (nb, k, nn) with the
 support-tile loop innermost, (bq, 1) accumulator resident in VMEM for
 the whole k x nn reduction; the per-member affine params ride in as
-(k, d) arrays read one row per member step. Zero-padded int8 support
+(k, 1, d) arrays read one (1, d) row per member step, coefs as
+(k, 1, n) and gammas whole in SMEM, so every block keeps the TPU's
+(8, 128) rule. Zero-padded int8 support
 rows dequantize to the member's zero-point vector (NOT 0), but their
 zero coefficients annihilate them in the coef matvec, so padding is
 still free.
@@ -48,19 +50,21 @@ def _ensemble_score_q8_kernel(x_ref, q_ref, scale_ref, zero_ref, coef_ref,
 
     x = x_ref[...].astype(jnp.float32)        # (bq, d)
     q = q_ref[0].astype(jnp.float32)          # (bn, d) int8 -> fp32 on the VPU
-    s = q * scale_ref[...] + zero_ref[...]    # member-t dequant in VMEM
-    c = coef_ref[0].astype(jnp.float32)       # (bn,)
-    g = gamma_ref[0, 0]                       # member-t bandwidth
+    s = q * scale_ref[0] + zero_ref[0]        # member-t dequant in VMEM
+    c = coef_ref[0].astype(jnp.float32)       # (1, bn)
+    g = gamma_ref[t]                          # member-t bandwidth (SMEM)
 
     x2 = jnp.sum(x * x, axis=1)[:, None]      # VPU
     s2 = jnp.sum(s * s, axis=1)[None, :]
     cross = jax.lax.dot_general(              # MXU: (bq, d) x (bn, d)^T
-        x, s, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, s, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     d2 = jnp.maximum(x2 + s2 - 2.0 * cross, 0.0)
-    part = jax.lax.dot_general(               # (bq, bn) x (bn, 1)
-        jnp.exp(-g * d2), c[:, None],
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+    part = jax.lax.dot_general(               # (bq, bn) x (1, bn)^T
+        jnp.exp(-g * d2), c,
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     acc_scr[...] += part * inv_k
 
@@ -88,10 +92,11 @@ def ensemble_score_q8_pallas(
     nn = -(-n_max // bn)
     xp = jnp.pad(x.astype(jnp.float32), ((0, nb * bq - b), (0, 0)))
     qp = jnp.pad(q.astype(jnp.int8), ((0, 0), (0, nn * bn - n_max), (0, 0)))
-    coefp = jnp.pad(coef.astype(jnp.float32), ((0, 0), (0, nn * bn - n_max)))
-    sc = scale.astype(jnp.float32)
-    ze = zero.astype(jnp.float32)
-    gam = gammas.astype(jnp.float32).reshape(k, 1)
+    coefp = jnp.pad(coef.astype(jnp.float32),
+                    ((0, 0), (0, nn * bn - n_max))).reshape(k, 1, nn * bn)
+    sc = scale.astype(jnp.float32).reshape(k, 1, d)
+    ze = zero.astype(jnp.float32).reshape(k, 1, d)
+    gam = gammas.astype(jnp.float32).reshape(k)
 
     kernel = functools.partial(
         _ensemble_score_q8_kernel, inv_k=1.0 / float(k), k=k, nn=nn
@@ -102,10 +107,10 @@ def ensemble_score_q8_pallas(
         in_specs=[
             pl.BlockSpec((bq, d), lambda i, t, j: (i, 0)),
             pl.BlockSpec((1, bn, d), lambda i, t, j: (t, j, 0)),
-            pl.BlockSpec((1, d), lambda i, t, j: (t, 0)),
-            pl.BlockSpec((1, d), lambda i, t, j: (t, 0)),
-            pl.BlockSpec((1, bn), lambda i, t, j: (t, j)),
-            pl.BlockSpec((1, 1), lambda i, t, j: (t, 0)),
+            pl.BlockSpec((1, 1, d), lambda i, t, j: (t, 0, 0)),
+            pl.BlockSpec((1, 1, d), lambda i, t, j: (t, 0, 0)),
+            pl.BlockSpec((1, 1, bn), lambda i, t, j: (t, 0, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bq, 1), lambda i, t, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * bq, 1), jnp.float32),

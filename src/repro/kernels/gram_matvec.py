@@ -46,7 +46,8 @@ def _gram_matvec_kernel(x1_ref, x2_ref, v_ref, o_ref, acc_scr, *, gamma: float, 
     sq1 = jnp.sum(x1 * x1, axis=1)[:, None]  # VPU
     sq2 = jnp.sum(x2 * x2, axis=1)[None, :]
     cross = jax.lax.dot_general(  # MXU: (bm, d) x (bn, d)^T
-        x1, x2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x1, x2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     d2 = jnp.maximum(sq1 + sq2 - 2.0 * cross, 0.0)
     # fused epilogue: exp + matvec slice while the tile is in VMEM.
@@ -54,6 +55,7 @@ def _gram_matvec_kernel(x1_ref, x2_ref, v_ref, o_ref, acc_scr, *, gamma: float, 
     part = jax.lax.dot_general(  # (bm, bn) x (bn, 1)
         jnp.exp(-gamma * d2), v,
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     acc_scr[...] += part
 

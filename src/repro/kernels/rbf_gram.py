@@ -26,7 +26,8 @@ def _rbf_gram_kernel(x1_ref, x2_ref, o_ref, *, gamma: float):
     sq1 = jnp.sum(x1 * x1, axis=1)[:, None]  # VPU
     sq2 = jnp.sum(x2 * x2, axis=1)[None, :]
     cross = jax.lax.dot_general(  # MXU: (bm, d) x (bn, d)^T
-        x1, x2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x1, x2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     d2 = jnp.maximum(sq1 + sq2 - 2.0 * cross, 0.0)
     o_ref[...] = jnp.exp(-gamma * d2)  # fused epilogue in VMEM

@@ -44,7 +44,8 @@ def _rbf_gram_q8_kernel(x_ref, q_ref, scale_ref, zero_ref, o_ref, *, gamma: floa
     sq1 = jnp.sum(x * x, axis=1)[:, None]     # VPU
     sq2 = jnp.sum(s * s, axis=1)[None, :]
     cross = jax.lax.dot_general(              # MXU: (bm, d) x (bn, d)^T
-        x, s, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, s, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     d2 = jnp.maximum(sq1 + sq2 - 2.0 * cross, 0.0)
     o_ref[...] = jnp.exp(-gamma * d2)         # fused epilogue in VMEM

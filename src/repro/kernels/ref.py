@@ -1,10 +1,14 @@
-"""Pure-jnp oracles for every Pallas kernel (the ground truth in tests)."""
+"""Pure-jnp oracles for every Pallas kernel (the ground truth in tests).
+
+Every contraction runs at ``HIGHEST`` precision, so an oracle is a plain
+float32 reference on any backend (see ``repro/serve/__init__.py``)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
 NEG_INF = -1e9
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def rbf_gram_ref(x1, x2, gamma: float):
@@ -13,7 +17,7 @@ def rbf_gram_ref(x1, x2, gamma: float):
     x2 = x2.astype(jnp.float32)
     sq1 = jnp.sum(x1 * x1, axis=1)[:, None]
     sq2 = jnp.sum(x2 * x2, axis=1)[None, :]
-    cross = x1 @ x2.T
+    cross = jnp.matmul(x1, x2.T, precision=_F32)
     d2 = jnp.maximum(sq1 + sq2 - 2.0 * cross, 0.0)
     return jnp.exp(-gamma * d2)
 
@@ -43,7 +47,7 @@ def gram_matvec_ref(x1, x2, v, gamma: float, row_chunk: int = 1024):
     x2 = x2.astype(jnp.float32)
     v = v.astype(jnp.float32)
     out = jax.lax.map(
-        lambda c: rbf_gram_ref(c, x2, gamma) @ v,
+        lambda c: jnp.dot(rbf_gram_ref(c, x2, gamma), v, precision=_F32),
         x1p.reshape(mp // chunk, chunk, d),
     )
     return out.reshape(-1)[:m]
@@ -72,7 +76,7 @@ def ensemble_score_ref(x, sup, coef, gammas):
     x = x.astype(jnp.float32)
 
     def member_scores(s, c, g):
-        return rbf_gram_ref(x, s, g) @ c
+        return jnp.dot(rbf_gram_ref(x, s, g), c, precision=_F32)
 
     scores = jax.vmap(member_scores)(
         sup.astype(jnp.float32), coef.astype(jnp.float32), gammas.astype(jnp.float32)

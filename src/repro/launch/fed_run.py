@@ -63,6 +63,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core import deepfed
 from repro.data import make_federated_lm_data, token_batches
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import ShardCtx
 from repro.obs import (Tracer, current_tracer, default_registry, envelope,
                        stopwatch, use_tracer)
@@ -279,6 +280,7 @@ def main(argv=None):
                          "(spans from engine/round/comm/distill/fleet; "
                          "open at https://ui.perfetto.dev)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.mode == "sim":
         return run_sim(args)

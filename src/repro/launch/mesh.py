@@ -9,11 +9,18 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    """A mesh whose axes the compiler shards by ``with_sharding_constraint``
+    hints (``jax.make_mesh`` otherwise makes them Explicit)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_sim_mesh(shards: int | None = None):
@@ -35,7 +42,7 @@ def make_debug_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many real devices exist (tests)."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def mesh_chips(mesh) -> int:

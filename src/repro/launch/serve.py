@@ -23,6 +23,7 @@ from repro.obs.trace import stopwatch
 
 from repro.configs import get_config
 from repro.data import make_federated_lm_data
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import (
     ShardCtx,
     init_cache,
@@ -77,6 +78,7 @@ def main(argv=None):
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

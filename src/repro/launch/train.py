@@ -22,6 +22,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import SHAPES, get_config
 from repro.data import make_federated_lm_data, token_batches
 from repro.launch import specs as S
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.models import ShardCtx, init_params, logical_axes, make_train_step
 from repro.sharding.rules import ShardingRules, logical_to_spec
@@ -53,6 +54,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

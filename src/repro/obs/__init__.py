@@ -34,7 +34,7 @@ from repro.obs.registry import (
     fleet_section,
     scheduler_section,
 )
-from repro.obs.profile import kernel_cost, maybe_profile, set_hardware, timed_call
+from repro.obs.profile import kernel_cost, maybe_profile, timed_call
 
 __all__ = [
     "NULL_TRACER",
@@ -54,6 +54,5 @@ __all__ = [
     "scheduler_section",
     "kernel_cost",
     "maybe_profile",
-    "set_hardware",
     "timed_call",
 ]

@@ -16,10 +16,11 @@ attributes carry the achieved-vs-roofline accounting:
   * ``roofline_bound_us`` / ``roofline_frac`` / ``dominant`` — the
     three-term model from ``roofline.analysis.roofline_report`` (no
     collective term for single-kernel calls): how close this call ran
-    to the hardware bound, and which term bounds it. The default
-    ``HardwareSpec`` is the V5E sheet the roofline package ships; on
-    this CPU container the fractions are honest and tiny — the point
-    is the *accounting* travels with the span either way.
+    to the hardware bound, and which term bounds it. The peaks come
+    from ``roofline.analysis.hardware_for`` keyed by the device's
+    ``device_kind``; an accelerator kind missing from that table
+    raises. Off the TPU, spans carry timing and XLA cost only — no
+    roofline share, since no peak sheet describes that backend.
 
 Non-jitted paths (the Pallas interpreter) have no ``lower``; their
 spans carry timing only. ``timed_call`` is the shared benchmark timing
@@ -35,20 +36,13 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 
 from repro.obs.trace import current_tracer
-from repro.roofline.analysis import V5E, HardwareSpec, roofline_report
+from repro.roofline.analysis import HardwareSpec, hardware_for, roofline_report
 from repro.utils.logging import get_logger, kv
 
 log = get_logger("obs")
 
 # (kernel name, arg signature) -> (flops, bytes) | None when unknowable
 _COST_CACHE: Dict[tuple, Optional[Tuple[float, float]]] = {}
-_HW: HardwareSpec = V5E
-
-
-def set_hardware(hw: HardwareSpec) -> None:
-    """Swap the roofline sheet kernel spans are priced against."""
-    global _HW
-    _HW = hw
 
 
 def _signature(args: tuple) -> tuple:
@@ -85,6 +79,14 @@ def kernel_cost(name: str, fn: Callable, args: tuple) -> Optional[Tuple[float, f
     return cost
 
 
+def peak_sheet(device) -> Optional[HardwareSpec]:
+    """The peaks spans on ``device`` are priced against: None off the
+    TPU; for a TPU, its ``device_kind``'s sheet (unknown kinds raise)."""
+    if device.platform != "tpu":
+        return None
+    return hardware_for(device.device_kind)
+
+
 def maybe_profile(name: str, fn: Callable, *args):
     """The ops.py dispatch hook: call through, and when a tracer is
     installed, time the call to completion and attach the roofline
@@ -100,16 +102,20 @@ def maybe_profile(name: str, fn: Callable, *args):
     attrs = {"backend": jax.default_backend(), "dur_s": dt}
     if cost is not None:
         flops, nbytes = cost
-        rl = roofline_report(flops, nbytes, 0.0, hw=_HW)
-        bound = rl["step_lower_bound_s"]
         attrs.update(
             flops=flops,
             bytes_accessed=nbytes,
             achieved_gflops=flops / max(dt, 1e-12) / 1e9,
-            roofline_bound_us=bound * 1e6,
-            roofline_frac=bound / max(dt, 1e-12),
-            dominant=rl["dominant"],
         )
+        hw = peak_sheet(jax.devices()[0])
+        if hw is not None:
+            rl = roofline_report(flops, nbytes, 0.0, hw=hw)
+            bound = rl["step_lower_bound_s"]
+            attrs.update(
+                roofline_bound_us=bound * 1e6,
+                roofline_frac=bound / max(dt, 1e-12),
+                dominant=rl["dominant"],
+            )
     ts = tracer.clock() if hasattr(tracer, "clock") else 0.0
     tracer.complete(f"kernel.{name}", ts - dt * 1e6, dt * 1e6,
                     cat="kernel", **attrs)

@@ -29,6 +29,22 @@ class HardwareSpec:
 
 V5E = HardwareSpec(name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9, link_bw=50e9)
 
+# Peak sheets keyed by ``jax.Device.device_kind``. Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# ICI per chip).
+HARDWARE_BY_KIND: Dict[str, HardwareSpec] = {"TPU v5 lite": V5E}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    """The peak sheet for a device kind; an unlisted kind is an error,
+    never a silent default."""
+    try:
+        return HARDWARE_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak sheet for device kind {device_kind!r}; add one to "
+            f"HARDWARE_BY_KIND with its source") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

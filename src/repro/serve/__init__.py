@@ -46,6 +46,11 @@ and the serve-path ``ensemble_score`` — route through
     kernel bodies without TPU hardware; it is far slower than the
     oracle and is not a serving configuration.
 
+The SVM kernels, their oracles and the engine's XLA contractions pin
+``Precision.HIGHEST``: on a TPU both XLA and Mosaic otherwise contract
+f32 in one bf16 pass (about 1e-1 error on a d=32 Gram entry), which
+would break kernel/oracle parity and the engine tiers' agreement.
+
 Every module that cares about dispatch (``kernels/ops.py``,
 ``benchmarks/run.py``) cross-references this docstring rather than
 restating the policy.

@@ -209,7 +209,7 @@ def _score_group_body(xq, sup, coef, gammas):
     from repro.kernels import ops as kops
 
     Kq = kops.batched_rbf_gram(xq, sup, gammas)  # (g, q, b)
-    return jnp.einsum("gqb,gb->gq", Kq, coef)
+    return jnp.einsum("gqb,gb->gq", Kq, coef, precision=jax.lax.Precision.HIGHEST)
 
 
 _fit_group = jax.jit(_fit_group_body, static_argnames=("epochs",))
@@ -259,8 +259,6 @@ def make_shard_ctx(shards: Optional[int] = None, epochs: int = 20) -> ShardCtx:
     same logical-axis table the LM side uses, with bucket groups on the
     logical "group" axis.
     """
-    from jax.experimental.shard_map import shard_map
-
     from repro.launch.mesh import make_sim_mesh
     from repro.sharding.rules import group_shard_specs
 
@@ -272,12 +270,12 @@ def make_shard_ctx(shards: Optional[int] = None, epochs: int = 20) -> ShardCtx:
     # fit: (xp, yp, n_real, gammas) sharded on the group axis; lam is a
     # replicated scalar; alpha comes back group-sharded (the gather).
     fit_specs = group_shard_specs(mesh, (3, 2, 1, 1, 0))
-    fit = jax.jit(shard_map(
+    fit = jax.jit(jax.shard_map(
         partial(_fit_group_body, epochs=epochs),
         mesh=mesh, in_specs=fit_specs, out_specs=fit_specs[1],
     ))
     score_specs = group_shard_specs(mesh, (3, 3, 2, 1))
-    score = jax.jit(shard_map(
+    score = jax.jit(jax.shard_map(
         _score_group_body,
         mesh=mesh, in_specs=score_specs, out_specs=score_specs[2],
     ))

@@ -75,8 +75,6 @@ def test_registry_shard_specs_preserve_dispatch(name):
     CPU; the forced multi-device CI lane exercises real splits). The
     mesh is capped at 4 shards so the fixed-size fixture batch axes
     (4 / 40 rows) always divide it, whatever the host exposes."""
-    from jax.experimental.shard_map import shard_map
-
     from repro.launch.mesh import make_sim_mesh
 
     spec = KERNEL_REGISTRY[name]
@@ -85,7 +83,7 @@ def test_registry_shard_specs_preserve_dispatch(name):
     in_specs, out_specs = spec.shard_specs(mesh)
     arrays = [a for a in args if hasattr(a, "shape")]
     statics = args[len(arrays):]  # trailing python scalars (gamma)
-    fn = shard_map(lambda *xs: spec.dispatch(*xs, *statics), mesh=mesh,
+    fn = jax.shard_map(lambda *xs: spec.dispatch(*xs, *statics), mesh=mesh,
                    in_specs=in_specs[: len(arrays)], out_specs=out_specs)
     np.testing.assert_allclose(
         np.asarray(fn(*arrays)), np.asarray(spec.dispatch(*args)),
