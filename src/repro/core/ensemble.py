@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.svm import SVMModel
+from repro.obs.trace import span
 
 
 def chunked_bucket_predict(score_fn, x: np.ndarray, chunk: int) -> np.ndarray:
@@ -82,18 +83,19 @@ class StackedEnsemble:
                     f"StackedEnsemble requires SVMModel members, got {type(m).__name__}; "
                     "use ensemble_predict_mean for mixed ensembles"
                 )
-        n_max = max(len(m.coef) for m in members)
-        d = members[0].support_x.shape[1]
-        k = len(members)
-        sup = np.zeros((k, n_max, d), np.float32)
-        coef = np.zeros((k, n_max), np.float32)
-        gammas = np.zeros((k,), np.float32)
-        for i, m in enumerate(members):
-            n = len(m.coef)
-            sup[i, :n] = m.support_x
-            coef[i, :n] = m.coef
-            gammas[i] = m.gamma
-        return cls(jnp.asarray(sup), jnp.asarray(coef), jnp.asarray(gammas))
+        with span("ensemble.pack"):
+            n_max = max(len(m.coef) for m in members)
+            d = members[0].support_x.shape[1]
+            k = len(members)
+            sup = np.zeros((k, n_max, d), np.float32)
+            coef = np.zeros((k, n_max), np.float32)
+            gammas = np.zeros((k,), np.float32)
+            for i, m in enumerate(members):
+                n = len(m.coef)
+                sup[i, :n] = m.support_x
+                coef[i, :n] = m.coef
+                gammas[i] = m.gamma
+            return cls(jnp.asarray(sup), jnp.asarray(coef), jnp.asarray(gammas))
 
     def score(self, x) -> jnp.ndarray:
         """Fused mean member score for one query block. x: (b, d) -> (b,)."""

@@ -57,7 +57,7 @@ import numpy as np
 
 from repro.core.svm import train_svm
 from repro.core.ensemble import Ensemble
-from repro.obs.trace import current_tracer
+from repro.obs.trace import span
 from repro.data.federated import FederatedDataset, DeviceData
 from repro.data.partition import pool_devices
 from repro.utils.metrics import roc_auc, streaming_grouped_auc
@@ -124,7 +124,8 @@ def _mean_auc_over_devices(
         ((d.device_id, d.splits["test"].x, d.splits["test"].y) for d in devices),
         chunk=chunk,
     )
-    per = ga.compute()
+    with span("eval.auc"):
+        per = ga.compute()
     aucs = np.array([per[d.device_id] for d in devices])
     return float(np.mean(aucs)), aucs
 
@@ -158,16 +159,15 @@ def run_protocol(
     elif distill.proxy_size == 0 and distill_proxy > 0:
         distill = dataclasses.replace(distill, proxy_size=distill_proxy)
 
-    tracer = current_tracer()
     m = dataset.n_devices
-    with tracer.span("round.train", cat="round", devices=m, engine=engine):
+    with span("round.train", cat="round", devices=m, engine=engine):
         devices = train_population(dataset, lam=lam, seed=seed,
                                    mode=engine).outcomes
     reports = [d.report for d in devices]
     eligible_ids = [r.device_id for r in reports if r.eligible]
 
     # --- the wire: priced uploads, decoded models, metadata on ledger ---
-    with tracer.span("round.encode", cat="round", codec=codec):
+    with span("round.encode", cat="round", codec=codec):
         ex = ModelExchange({d.device_id: d.model for d in devices}, reports,
                            codec=codec, budget_bytes=budget_bytes)
     codec_spec = ex.codec
@@ -185,13 +185,14 @@ def run_protocol(
         return by_id
 
     # --- local baseline (paper Fig. 1 "local") ---
-    local_aucs = [
-        roc_auc(d.splits["test"].y, d.local_test_scores) for d in devices
-    ]
+    with span("round.local", cat="round"):
+        local_aucs = [
+            roc_auc(d.splits["test"].y, d.local_test_scores) for d in devices
+        ]
     local_mean = float(np.mean(local_aucs))
 
     # --- unattainable ideal: pooled-data SVM (subsampled for tractability) ---
-    with tracer.span("round.ideal", cat="round", cap=ideal_cap):
+    with span("round.ideal", cat="round", cap=ideal_cap):
         pooled = pool_devices([d.splits["train"] for d in devices])
         rng = np.random.default_rng(seed)
         if len(pooled.y) > ideal_cap:
@@ -208,43 +209,41 @@ def run_protocol(
     cell_scorers: Dict[tuple, object] = {}
     for strat in strategies:
         ensemble_auc[strat] = {}
-        strat_span = tracer.span("round.select", cat="round", strategy=strat)
-        strat_span.__enter__()
-        for k in ks:
-            extra_tag = f"agg_extra_{strat}_k{k}"
-            if strat == "random":
-                trials = []
-                for t in range(random_trials):
-                    tids = ex.pick("random", k, seed + 17 * t)
-                    if not tids:
+        with span("round.select", cat="round", strategy=strat):
+            for k in ks:
+                extra_tag = f"agg_extra_{strat}_k{k}"
+                if strat == "random":
+                    trials = []
+                    for t in range(random_trials):
+                        tids = ex.pick("random", k, seed + 17 * t)
+                        if not tids:
+                            continue
+                        scorer = build_cell(agg, ex, tids, outcomes_for, ledger,
+                                            extra_tag, seed, record=False)
+                        auc, _ = _mean_auc_over_devices(
+                            devices, partial(scorer.predict, chunk=eval_chunk), eval_chunk)
+                        trials.append(auc)
+                    if trials:
+                        ensemble_auc[strat][k] = float(np.mean(trials))
+                    ids = ex.pick("random", k, seed)
+                    if ids:
+                        cell_scorers[(strat, k)] = build_cell(
+                            agg, ex, ids, outcomes_for, ledger, extra_tag, seed)
+                else:
+                    ids = ex.pick(strat, k, seed)
+                    if not ids:
                         continue
-                    scorer = build_cell(agg, ex, tids, outcomes_for, ledger,
-                                        extra_tag, seed, record=False)
+                    scorer = build_cell(agg, ex, ids, outcomes_for, ledger,
+                                        extra_tag, seed)
+                    cell_scorers[(strat, k)] = scorer
                     auc, _ = _mean_auc_over_devices(
                         devices, partial(scorer.predict, chunk=eval_chunk), eval_chunk)
-                    trials.append(auc)
-                if trials:
-                    ensemble_auc[strat][k] = float(np.mean(trials))
-                ids = ex.pick("random", k, seed)
-                if ids:
-                    cell_scorers[(strat, k)] = build_cell(
-                        agg, ex, ids, outcomes_for, ledger, extra_tag, seed)
-            else:
-                ids = ex.pick(strat, k, seed)
-                if not ids:
-                    continue
-                scorer = build_cell(agg, ex, ids, outcomes_for, ledger,
-                                    extra_tag, seed)
-                cell_scorers[(strat, k)] = scorer
-                auc, _ = _mean_auc_over_devices(
-                    devices, partial(scorer.predict, chunk=eval_chunk), eval_chunk)
-                ensemble_auc[strat][k] = auc
-            ex.record_uploads(ledger, ids, f"upload_{strat}_k{k}")
-        strat_span.__exit__(None, None, None)
+                    ensemble_auc[strat][k] = auc
+                ex.record_uploads(ledger, ids, f"upload_{strat}_k{k}")
         log.info("%s/%s: %s", dataset.name, strat, ensemble_auc[strat])
 
     # --- full ensemble of all eligible devices ---
-    with tracer.span("round.eval", cat="round", ensemble=len(eligible_ids)):
+    with span("round.eval", cat="round", ensemble=len(eligible_ids)):
         full_ens = Ensemble([ex.received(i) for i in eligible_ids])
         full_auc, full_aucs = _mean_auc_over_devices(
             devices, partial(full_ens.predict, chunk=eval_chunk), eval_chunk)
@@ -286,7 +285,8 @@ def run_protocol(
         dr = distill_round(teacher.predict, devices, distill, seed, codec_spec,
                            ledger, dim=dataset.dim)
         student_recv, student_codec = dr.student, dr.codec
-        dist_auc, dist_aucs = _mean_auc_over_devices(devices, student_recv.predict)
+        with span("round.student", cat="round"):
+            dist_auc, dist_aucs = _mean_auc_over_devices(devices, student_recv.predict)
         per_device["distilled"] = dist_aucs
         ledger.record("down", "ensemble_download", ex.ensemble_nbytes(ids),
                       codec=codec_spec, tag="download_ensemble")

@@ -18,7 +18,7 @@ import numpy as np
 from repro.distill.config import DistillConfig
 from repro.distill.proxy import make_proxy
 from repro.distill.solvers import distill_rng, distill_teacher
-from repro.obs.trace import current_tracer
+from repro.obs.trace import span
 
 
 @dataclasses.dataclass
@@ -53,9 +53,8 @@ def distill_round(
     """
     from repro.comm import decode, encode  # deferred: comm <-> core cycle
 
-    with current_tracer().span("distill.round", cat="distill",
-                               solver=cfg.solver, proxy=cfg.proxy,
-                               proxy_size=cfg.proxy_size):
+    with span("distill.round", cat="distill", solver=cfg.solver,
+              proxy=cfg.proxy, proxy_size=cfg.proxy_size):
         params = dict(cfg.proxy_params)
         for key, val in dict(default_proxy_params or {}).items():
             params.setdefault(key, val)

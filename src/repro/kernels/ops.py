@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
-from repro.obs.profile import maybe_profile
 from repro.kernels.gram_matvec import gram_matvec_pallas
 from repro.kernels.rbf_gram import rbf_gram_pallas
 from repro.kernels.rbf_gram_q8 import rbf_gram_q8_pallas
@@ -49,11 +48,10 @@ def _rbf_ref(x1, x2, gamma):
 def rbf_gram(x1, x2, gamma: float):
     gamma = float(gamma)
     if _on_tpu():
-        return maybe_profile("rbf_gram", _rbf_tpu, x1, x2, gamma)
+        return _rbf_tpu(x1, x2, gamma)
     if _force_interpret():
-        return maybe_profile(
-            "rbf_gram", partial(rbf_gram_pallas, interpret=True), x1, x2, gamma)
-    return maybe_profile("rbf_gram", _rbf_ref, x1, x2, gamma)
+        return rbf_gram_pallas(x1, x2, gamma, interpret=True)
+    return _rbf_ref(x1, x2, gamma)
 
 
 @partial(jax.jit, static_argnames=("gamma",))
@@ -75,12 +73,10 @@ def gram_matvec(x1, x2, v, gamma: float):
     """
     gamma = float(gamma)
     if _on_tpu():
-        return maybe_profile("gram_matvec", _gmv_tpu, x1, x2, v, gamma)
+        return _gmv_tpu(x1, x2, v, gamma)
     if _force_interpret():
-        return maybe_profile(
-            "gram_matvec", partial(gram_matvec_pallas, interpret=True),
-            x1, x2, v, gamma)
-    return maybe_profile("gram_matvec", _gmv_ref, x1, x2, v, gamma)
+        return gram_matvec_pallas(x1, x2, v, gamma, interpret=True)
+    return _gmv_ref(x1, x2, v, gamma)
 
 
 @partial(jax.jit, static_argnames=("gamma",))
@@ -104,12 +100,10 @@ def rbf_gram_q8(x, q, scale, zero, gamma: float):
     """
     gamma = float(gamma)
     if _on_tpu():
-        return maybe_profile("rbf_gram_q8", _q8_tpu, x, q, scale, zero, gamma)
+        return _q8_tpu(x, q, scale, zero, gamma)
     if _force_interpret():
-        return maybe_profile(
-            "rbf_gram_q8", partial(rbf_gram_q8_pallas, interpret=True),
-            x, q, scale, zero, gamma)
-    return maybe_profile("rbf_gram_q8", _q8_ref, x, q, scale, zero, gamma)
+        return rbf_gram_q8_pallas(x, q, scale, zero, gamma, interpret=True)
+    return _q8_ref(x, q, scale, zero, gamma)
 
 
 @jax.jit
@@ -130,12 +124,10 @@ def batched_rbf_gram(x1, x2, gammas):
     engine's vmap fallback. Callers mask padded rows/cols themselves.
     """
     if _on_tpu():
-        return maybe_profile("batched_rbf_gram", _bgram_tpu, x1, x2, gammas)
+        return _bgram_tpu(x1, x2, gammas)
     if _force_interpret():
-        return maybe_profile(
-            "batched_rbf_gram", partial(batched_rbf_gram_pallas, interpret=True),
-            x1, x2, gammas)
-    return maybe_profile("batched_rbf_gram", _bgram_ref, x1, x2, gammas)
+        return batched_rbf_gram_pallas(x1, x2, gammas, interpret=True)
+    return _bgram_ref(x1, x2, gammas)
 
 
 @partial(jax.jit, static_argnames=("causal", "window"))
@@ -150,13 +142,11 @@ def _flash_ref(q, k, v, causal, window):
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0):
     if _on_tpu():
-        return maybe_profile("flash_attention", _flash_tpu, q, k, v, causal, window)
+        return _flash_tpu(q, k, v, causal, window)
     if _force_interpret():
-        return maybe_profile(
-            "flash_attention",
-            partial(flash_attention_pallas, causal=causal, window=window,
-                    interpret=True), q, k, v)
-    return maybe_profile("flash_attention", _flash_ref, q, k, v, causal, window)
+        return flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                      interpret=True)
+    return _flash_ref(q, k, v, causal, window)
 
 
 @jax.jit
@@ -177,12 +167,10 @@ def ensemble_score(x, sup, coef, gammas):
     (k, b, n_max) Gram tensor in HBM.
     """
     if _on_tpu():
-        return maybe_profile("ensemble_score", _ens_tpu, x, sup, coef, gammas)
+        return _ens_tpu(x, sup, coef, gammas)
     if _force_interpret():
-        return maybe_profile(
-            "ensemble_score", partial(ensemble_score_pallas, interpret=True),
-            x, sup, coef, gammas)
-    return maybe_profile("ensemble_score", _ens_ref, x, sup, coef, gammas)
+        return ensemble_score_pallas(x, sup, coef, gammas, interpret=True)
+    return _ens_ref(x, sup, coef, gammas)
 
 
 @jax.jit
@@ -205,15 +193,11 @@ def ensemble_score_q8(x, q, scale, zero, coef, gammas):
     the fly in VMEM.
     """
     if _on_tpu():
-        return maybe_profile(
-            "ensemble_score_q8", _ens_q8_tpu, x, q, scale, zero, coef, gammas)
+        return _ens_q8_tpu(x, q, scale, zero, coef, gammas)
     if _force_interpret():
-        return maybe_profile(
-            "ensemble_score_q8",
-            partial(ensemble_score_q8_pallas, interpret=True),
-            x, q, scale, zero, coef, gammas)
-    return maybe_profile(
-        "ensemble_score_q8", _ens_q8_ref, x, q, scale, zero, coef, gammas)
+        return ensemble_score_q8_pallas(x, q, scale, zero, coef, gammas,
+                                        interpret=True)
+    return _ens_q8_ref(x, q, scale, zero, coef, gammas)
 
 
 # ----------------------------------------------------------------------

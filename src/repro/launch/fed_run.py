@@ -65,8 +65,8 @@ from repro.core import deepfed
 from repro.data import make_federated_lm_data, token_batches
 from repro.launch.compile_cache import use_compile_cache
 from repro.models import ShardCtx
-from repro.obs import (Tracer, current_tracer, default_registry, envelope,
-                       stopwatch, use_tracer)
+from repro.obs import (Tracer, default_registry, envelope, span, stopwatch,
+                       use_tracer)
 from repro.utils.logging import get_logger
 
 log = get_logger("fed_run")
@@ -307,7 +307,7 @@ def main(argv=None):
     stacked = deepfed.stacked_init(cfg, M, key)
     train = deepfed.make_local_train(cfg, lr=args.lr)
     elapsed = stopwatch()
-    with current_tracer().span("lm.local_train", cat="round", clients=M):
+    with span("lm.local_train", cat="round", clients=M):
         stacked, losses = train(stacked, wins)
     t_local = elapsed()
     log.info(
@@ -328,8 +328,7 @@ def main(argv=None):
         np.stack([next(token_batches(clients[i % M], B, S, seed=args.seed + 13)) for i in range(M)])
     )
     elapsed = stopwatch()
-    with current_tracer().span("lm.distill", cat="distill",
-                               steps=args.distill_steps):
+    with span("lm.distill", cat="distill", steps=args.distill_steps):
         student, dlosses = deepfed.distill_to_student(
             cfg, cfg, stacked, proxy, steps=args.distill_steps, lr=args.lr,
             loss_kind=args.distill_loss, seed=args.seed,

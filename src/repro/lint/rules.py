@@ -146,11 +146,12 @@ def kernel_registry_bypass(ctx: FileContext) -> Iterator[Violation]:
     """Every kernel call goes through the registry dispatch.
 
     ``kernels/ops.py`` owns backend choice (TPU pallas / interpret /
-    jnp oracle), jit caching, and the ``maybe_profile`` roofline hook;
-    the ROADMAP autotuner will hang tile-config choice off the same
-    dispatchers. A direct ``*_pallas`` or ``ref.*_ref`` call sidesteps
-    all three — it runs uncompiled off-TPU, unprofiled everywhere, and
-    will silently miss autotuned tile configs. Only ``repro/kernels``
+    jnp oracle) and jit caching under the wrapper names the device
+    trace is read by; the ROADMAP autotuner will hang tile-config
+    choice off the same dispatchers. A direct ``*_pallas`` or
+    ``ref.*_ref`` call sidesteps all three — it runs uncompiled
+    off-TPU, under no name a trace reader knows, and will silently
+    miss autotuned tile configs. Only ``repro/kernels``
     itself and the kernel parity suite touch implementations directly.
     """
     ref_aliases = {

@@ -4,16 +4,19 @@ Three layers, one discipline (see docs/ARCHITECTURE.md):
 
   * ``trace``    span tracer → Chrome trace-event JSON (Perfetto);
                  wall clock for engines/benchmarks, ``sim_clock`` for
-                 the fleet so fleet traces are byte-reproducible
+                 the fleet so fleet traces are byte-reproducible;
+                 ``span`` is the entry point instrumented code calls,
+                 and writes into any recording ``jax.profiler`` trace
   * ``registry`` named counters/gauges/histograms + the schema-
                  versioned envelope the existing metric silos
                  (CommLedger, FleetMetrics, SchedulerStats) export
                  through
-  * ``profile``  kernel dispatch hooks: timed compiled calls with
-                 achieved-vs-roofline FLOPs/bytes attributes
+  * ``profile``  benchmark timing (``timed_call``) and the peak sheet
+                 of a TPU (``peak_sheet``); kernel time comes from the
+                 device trace, not from a dispatch hook
 
-Everything is gated behind the null tracer: uninstrumented runs pay
-one attribute check per site.
+With no tracer installed and no profiler recording, a span is one
+reusable no-op context manager.
 """
 from repro.obs.trace import (
     NULL_TRACER,
@@ -21,6 +24,7 @@ from repro.obs.trace import (
     Tracer,
     current_tracer,
     sim_clock,
+    span,
     stopwatch,
     traced,
     use_tracer,
@@ -34,7 +38,7 @@ from repro.obs.registry import (
     fleet_section,
     scheduler_section,
 )
-from repro.obs.profile import kernel_cost, maybe_profile, timed_call
+from repro.obs.profile import timed_call
 
 __all__ = [
     "NULL_TRACER",
@@ -42,6 +46,7 @@ __all__ = [
     "Tracer",
     "current_tracer",
     "sim_clock",
+    "span",
     "stopwatch",
     "traced",
     "use_tracer",
@@ -52,7 +57,5 @@ __all__ = [
     "envelope",
     "fleet_section",
     "scheduler_section",
-    "kernel_cost",
-    "maybe_profile",
     "timed_call",
 ]

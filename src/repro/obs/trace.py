@@ -28,11 +28,17 @@ Two clock sources, one per determinism regime (docs/TESTING.md):
     timestamps either way, so any tracer they land in stays
     deterministic.
 
-Every hot path is gated behind the module-level *null tracer*: with no
-tracer installed, ``current_tracer()`` returns ``NULL_TRACER`` whose
-``enabled`` is False and whose ``span`` hands back one reusable no-op
-context manager — instrumented code costs one attribute check when
-tracing is off (the overhead bar in tests/test_obs.py). Install a real
+Instrumented code opens spans through the module-level ``span(name)``:
+it opens a span on the installed tracer and, while a ``jax.profiler``
+session is recording, a ``jax.profiler.TraceAnnotation`` of the same
+name, so the span also lands on the profiler's host plane, on the same
+clock as the device ops. With no tracer installed, ``current_tracer()``
+returns ``NULL_TRACER`` whose ``enabled`` is False and whose ``span``
+hands back one reusable no-op context manager, and with no profiler
+recording no annotation is built: a span then costs a function call and
+one check of the profiler (the overhead bar in tests/test_obs.py).
+Instrumented code calls ``span`` unconditionally — a duck-typed tracer
+whose ``enabled`` is False still receives every span. Install a real
 tracer for a region with::
 
     tracer = Tracer()
@@ -52,6 +58,8 @@ import functools
 import json
 import time
 from typing import Callable, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.utils.logging import get_logger, kv
 
@@ -81,6 +89,12 @@ def stopwatch() -> Callable[[], float]:
     """
     t0 = time.perf_counter()
     return lambda: time.perf_counter() - t0
+
+
+# Nanoseconds on the monotonic clock, for counters that sum waits
+# (``SchedulerStats.queue_wait_ns``); read through this module so code
+# outside ``repro.obs`` holds no clock read of its own.
+now_ns = time.perf_counter_ns
 
 
 def sim_clock(clock) -> Callable[[], float]:
@@ -123,8 +137,8 @@ _NULL_SPAN = _NullSpan()
 
 
 class NullTracer:
-    """Does nothing, as fast as possible. ``enabled`` is the one-check
-    gate instrumented hot paths use before building attributes."""
+    """Does nothing, as fast as possible. ``enabled`` gates events that
+    cost something to build; spans never check it."""
 
     enabled = False
 
@@ -245,7 +259,8 @@ _CURRENT: object = NULL_TRACER
 
 def current_tracer():
     """The installed tracer (``NULL_TRACER`` unless ``use_tracer`` is
-    active). Hot paths check ``.enabled`` before building attrs."""
+    active). Spans go through ``span``; ``.enabled`` gates only events
+    that cost something to build (comm instants, fleet completes)."""
     return _CURRENT
 
 
@@ -261,17 +276,46 @@ def use_tracer(tracer) -> Iterator[object]:
         _CURRENT = prev
 
 
+class _Annotated:
+    """A tracer span inside a profiler annotation of the same name."""
+
+    __slots__ = ("_span", "_mark")
+
+    def __init__(self, span, mark):
+        self._span, self._mark = span, mark
+
+    def __enter__(self):
+        self._mark.__enter__()
+        self._span.__enter__()
+        return None
+
+    def __exit__(self, *exc):
+        try:
+            self._span.__exit__(*exc)
+        finally:
+            self._mark.__exit__(*exc)
+        return False
+
+
+def span(name: str, cat: str = "app", **attrs):
+    """The one span entry point for instrumented code: a span on the
+    installed tracer, inside a ``jax.profiler.TraceAnnotation`` while a
+    profiler session is recording (``TraceMe.is_enabled``, checked once
+    a span). Hot-path spans pass no attributes, or ints already at hand."""
+    s = _CURRENT.span(name, cat=cat, **attrs)
+    if TraceAnnotation.is_enabled():
+        return _Annotated(s, TraceAnnotation(name))
+    return s
+
+
 def traced(name: Optional[str] = None, cat: str = "app") -> Callable:
-    """Decorator form: span the whole call on the current tracer."""
+    """Decorator form: ``span`` the whole call."""
     def deco(fn):
         span_name = name or fn.__qualname__
 
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
-            t = _CURRENT
-            if not t.enabled:
-                return fn(*args, **kwargs)
-            with t.span(span_name, cat=cat):
+            with span(span_name, cat=cat):
                 return fn(*args, **kwargs)
         return wrapper
     return deco

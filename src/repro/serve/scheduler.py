@@ -16,6 +16,14 @@ where row i of the output answers row i of the input; padded rows are
 zeros and their outputs are discarded. Kernel dispatch below the
 score_fn (TPU Pallas vs. CPU oracle vs. ``REPRO_PALLAS_INTERPRET``) is
 documented once in the ``repro.serve`` package docstring.
+
+A flush is the span ``serve.flush``; inside it ``serve.assemble``
+(queue pops, cache lookups and dedupe; stacking and bucket padding),
+``serve.score`` (the score_fn call through its host copy) and
+``serve.scatter`` (per-row results, done order, cache puts, eviction).
+``SchedulerStats.queue_wait_ns`` sums each request's wait from
+``submit()`` to its pop into a batch; the clock is only read, so it
+never steers which rows batch together.
 """
 from __future__ import annotations
 
@@ -26,6 +34,8 @@ from typing import Any, Callable, Deque, Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs import trace as obs_trace
+from repro.obs.trace import span
 from repro.serve.cache import LRUCache, query_key
 
 
@@ -73,6 +83,10 @@ class SchedulerStats:
     batches: int = 0
     scored_rows: int = 0
     padded_rows: int = 0
+    # ns from submit() to the pop into a batch, summed over every
+    # popped request (scored, answered from cache or deduped); a batch
+    # re-queued after a failed scoring call waits again
+    queue_wait_ns: int = 0
 
 
 @dataclasses.dataclass
@@ -82,6 +96,7 @@ class _Pending:
     result: Any = None
     done: bool = False
     key: Any = None  # query_key, computed once in flush() when caching
+    submitted_ns: int = 0
 
 
 class MicroBatchScheduler:
@@ -115,7 +130,7 @@ class MicroBatchScheduler:
         t = self._next_ticket
         self._next_ticket += 1
         # copy: callers may legally reuse one buffer across submits
-        p = _Pending(t, np.array(row, copy=True))
+        p = _Pending(t, np.array(row, copy=True), submitted_ns=obs_trace.now_ns())
         self._queue.append(p)
         self._results[t] = p
         self.stats.submitted += 1
@@ -134,49 +149,63 @@ class MicroBatchScheduler:
     # -- scoring side ---------------------------------------------------
     def flush(self) -> int:
         """Drain the queue; returns the number of scoring calls made."""
-        calls = 0
-        caching = self.cache.capacity > 0  # skip key serialization when off
-        while self._queue:
-            batch: List[_Pending] = []
-            in_batch: Dict[Any, _Pending] = {}
-            dups: List[_Pending] = []
-            while self._queue and len(batch) < self.config.max_batch:
-                p = self._queue.popleft()
-                hit = None
-                if caching:
-                    p.key = query_key(p.row)
-                    hit = self.cache.get(p.key)
-                if hit is not None:
-                    # copy across the cache boundary: a caller mutating
-                    # its result must never poison later hits
-                    p.result, p.done = np.copy(hit), True
-                    self._done[p.ticket] = None
-                    self.stats.answered_from_cache += 1
-                elif caching and p.key in in_batch:
-                    # hot-burst dedupe: identical rows queued before the
-                    # cache is warm score once and fan out
-                    dups.append(p)
-                else:
-                    batch.append(p)
-                    if caching:
-                        in_batch[p.key] = p
-            if batch:
-                try:
-                    self._score_batch(batch)
-                except Exception:
-                    # re-queue the in-flight batch (and its duplicates) in
-                    # submission order so a retrying flush() rescores them
-                    # instead of stranding undone tickets forever
-                    requeue = sorted(batch + dups, key=lambda p: p.ticket)
-                    self._queue.extendleft(reversed(requeue))
-                    raise
-                calls += 1
-            for p in dups:
-                p.result, p.done = np.copy(in_batch[p.key].result), True
-                self._done[p.ticket] = None
-                self.stats.deduped_in_flight += 1
-        self._evict_uncollected()
+        with span("serve.flush"):
+            calls = 0
+            caching = self.cache.capacity > 0  # skip key serialization when off
+            while self._queue:
+                with span("serve.assemble"):
+                    batch, in_batch, dups = self._take_batch(caching)
+                if batch:
+                    try:
+                        self._score_batch(batch)
+                    except Exception:
+                        # re-queue the in-flight batch (and its duplicates) in
+                        # submission order so a retrying flush() rescores them
+                        # instead of stranding undone tickets forever
+                        requeue = sorted(batch + dups, key=lambda p: p.ticket)
+                        self._queue.extendleft(reversed(requeue))
+                        raise
+                    calls += 1
+                if dups:
+                    with span("serve.scatter"):
+                        for p in dups:
+                            p.result, p.done = np.copy(in_batch[p.key].result), True
+                            self._done[p.ticket] = None
+                            self.stats.deduped_in_flight += 1
+            with span("serve.scatter"):
+                self._evict_uncollected()
         return calls
+
+    def _take_batch(self, caching: bool):
+        """Pop up to ``max_batch`` uncached rows off the queue: (batch,
+        its rows by cache key, in-flight duplicates). Cache hits are
+        answered here."""
+        batch: List[_Pending] = []
+        in_batch: Dict[Any, _Pending] = {}
+        dups: List[_Pending] = []
+        while self._queue and len(batch) < self.config.max_batch:
+            p = self._queue.popleft()
+            # read through the module so a test can stand in its clock
+            self.stats.queue_wait_ns += obs_trace.now_ns() - p.submitted_ns
+            hit = None
+            if caching:
+                p.key = query_key(p.row)
+                hit = self.cache.get(p.key)
+            if hit is not None:
+                # copy across the cache boundary: a caller mutating
+                # its result must never poison later hits
+                p.result, p.done = np.copy(hit), True
+                self._done[p.ticket] = None
+                self.stats.answered_from_cache += 1
+            elif caching and p.key in in_batch:
+                # hot-burst dedupe: identical rows queued before the
+                # cache is warm score once and fan out
+                dups.append(p)
+            else:
+                batch.append(p)
+                if caching:
+                    in_batch[p.key] = p
+        return batch, in_batch, dups
 
     def _evict_uncollected(self) -> None:
         """Bound memory under abandoned tickets: keep at most
@@ -195,26 +224,29 @@ class MicroBatchScheduler:
 
     def _score_batch(self, batch: List[_Pending]) -> None:
         n = len(batch)
-        bucket = self.config.bucket_for(n)
-        rows = np.stack([p.row for p in batch])
-        padded = np.zeros((bucket,) + rows.shape[1:], rows.dtype)
-        padded[:n] = rows
-        out = np.asarray(self.score_fn(padded))
+        with span("serve.assemble"):
+            bucket = self.config.bucket_for(n)
+            rows = np.stack([p.row for p in batch])
+            padded = np.zeros((bucket,) + rows.shape[1:], rows.dtype)
+            padded[:n] = rows
+        with span("serve.score"):
+            out = np.asarray(self.score_fn(padded))
         if out.shape[0] != bucket:
             raise ValueError(
                 f"score_fn returned leading dim {out.shape[0]}, expected bucket {bucket}"
             )
-        caching = self.cache.capacity > 0
-        for i, p in enumerate(batch):
-            # copy: out[i] is a view — don't pin the whole bucket output
-            # per ticket or expose sibling rows via result.base
-            p.result, p.done = np.copy(out[i]), True
-            self._done[p.ticket] = None
-            if caching:
-                self.cache.put(p.key, np.copy(out[i]))
-        self.stats.batches += 1
-        self.stats.scored_rows += n
-        self.stats.padded_rows += bucket - n
+        with span("serve.scatter"):
+            caching = self.cache.capacity > 0
+            for i, p in enumerate(batch):
+                # copy: out[i] is a view — don't pin the whole bucket output
+                # per ticket or expose sibling rows via result.base
+                p.result, p.done = np.copy(out[i]), True
+                self._done[p.ticket] = None
+                if caching:
+                    self.cache.put(p.key, np.copy(out[i]))
+            self.stats.batches += 1
+            self.stats.scored_rows += n
+            self.stats.padded_rows += bucket - n
 
     # -- response side --------------------------------------------------
     def result(self, ticket: int):

@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.core.ensemble import Ensemble, StackedEnsemble
 from repro.core.svm import SVMModel
+from repro.obs.trace import span
 from repro.serve.scheduler import MicroBatchScheduler, ServeConfig
 from repro.utils.metrics import GroupedAUC, streaming_grouped_auc
 
@@ -67,7 +68,10 @@ class EnsembleScorer:
         return self.stacked.k
 
     def __call__(self, batch: np.ndarray) -> np.ndarray:
-        return np.asarray(self.stacked.score(batch))
+        with span("serve.dispatch"):        # upload and kernel enqueue
+            out = self.stacked.score(batch)
+        with span("serve.fetch"):           # wait for the device, copy back
+            return np.asarray(out)
 
     def scheduler(self, config: ServeConfig = ServeConfig()) -> MicroBatchScheduler:
         """A micro-batching scheduler serving this ensemble."""

@@ -93,7 +93,7 @@ from repro.core.selection import DeviceReport
 from repro.data.federated import DeviceData, FederatedDataset
 from repro.data.partition import derive_device_seed, split_train_test_val
 from repro.obs.registry import default_registry
-from repro.obs.trace import current_tracer, stopwatch
+from repro.obs.trace import span, stopwatch
 from repro.utils.metrics import roc_auc
 from repro.utils.logging import get_logger
 
@@ -314,11 +314,12 @@ def _train_bucket_group(
     gamma_list = [default_gamma(t.x) for t in trains]
     gammas = np.ones(g, np.float32)
     gammas[:g_real] = gamma_list
-    xp = np.zeros((g, bucket, trains[0].x.shape[1]), np.float32)
-    yp = np.ones((g, bucket), np.float32)  # +1 padding, as in train_svm
-    for i, t in enumerate(trains):
-        xp[i, : t.n] = t.x
-        yp[i, : t.n] = t.y
+    with span("engine.pack"):
+        xp = np.zeros((g, bucket, trains[0].x.shape[1]), np.float32)
+        yp = np.ones((g, bucket), np.float32)  # +1 padding, as in train_svm
+        for i, t in enumerate(trains):
+            xp[i, : t.n] = t.x
+            yp[i, : t.n] = t.y
 
     fit_args = (jnp.asarray(xp), jnp.asarray(yp), jnp.asarray(n_real),
                 jnp.asarray(gammas), lam)
@@ -331,31 +332,33 @@ def _train_bucket_group(
 
     scores: Dict[str, np.ndarray] = {}
     for split in ("val", "test"):
-        qs = [sp[split].x for _, sp in members]
-        q = -(-max(len(a) for a in qs) // QUERY_PAD) * QUERY_PAD
-        xq = np.zeros((g, q, xp.shape[2]), np.float32)
-        for i, a in enumerate(qs):
-            xq[i, : len(a)] = a
+        with span("engine.pack"):
+            qs = [sp[split].x for _, sp in members]
+            q = -(-max(len(a) for a in qs) // QUERY_PAD) * QUERY_PAD
+            xq = np.zeros((g, q, xp.shape[2]), np.float32)
+            for i, a in enumerate(qs):
+                xq[i, : len(a)] = a
         scores[split] = np.asarray(
             score_fn(jnp.asarray(xq), jnp.asarray(xp),
                      jnp.asarray(coef.astype(np.float32)), jnp.asarray(gammas))
         )
 
     outcomes = []
-    for i, (dev_id, splits) in enumerate(members):
-        tr, va, te = splits["train"], splits["val"], splits["test"]
-        model = SVMModel(
-            support_x=tr.x.astype(np.float32),
-            coef=coef[i, : tr.n].astype(np.float32),
-            gamma=gamma_list[i],
-        )
-        val_scores = scores["val"][i, : va.n]
-        report = DeviceReport(dev_id, tr.n, roc_auc(va.y, val_scores), eligible=True)
-        outcomes.append(DeviceOutcome(
-            dev_id, splits, model, report,
-            val_scores=val_scores,
-            local_test_scores=scores["test"][i, : te.n],
-        ))
+    with span("engine.outcomes"):
+        for i, (dev_id, splits) in enumerate(members):
+            tr, va, te = splits["train"], splits["val"], splits["test"]
+            model = SVMModel(
+                support_x=tr.x.astype(np.float32),
+                coef=coef[i, : tr.n].astype(np.float32),
+                gamma=gamma_list[i],
+            )
+            val_scores = scores["val"][i, : va.n]
+            report = DeviceReport(dev_id, tr.n, roc_auc(va.y, val_scores), eligible=True)
+            outcomes.append(DeviceOutcome(
+                dev_id, splits, model, report,
+                val_scores=val_scores,
+                local_test_scores=scores["test"][i, : te.n],
+            ))
     return outcomes
 
 
@@ -390,15 +393,14 @@ def _train_buckets(by_bucket, lam, epochs, group_cap, shard):
 
     Each group is a ``cat="engine"`` span; the span closes before the
     yield so consumer work between yields never lands inside it."""
-    tracer = current_tracer()
     reg = default_registry()
     for bucket in sorted(by_bucket):
         members = by_bucket[bucket]
         cap = _bucket_group_caps(bucket, group_cap, shard)
         for lo in range(0, len(members), cap):
             elapsed = stopwatch()
-            with tracer.span("engine.group", cat="engine", bucket=bucket,
-                             members=len(members[lo : lo + cap]), cap=cap):
+            with span("engine.group", cat="engine", bucket=bucket,
+                      members=len(members[lo : lo + cap]), cap=cap):
                 outs = _train_bucket_group(
                     members[lo : lo + cap], bucket, lam, epochs,
                     pad_floor=min(8, cap), shard=shard,
@@ -545,11 +547,10 @@ def _iter_streamed(
         total = sum(1 for i in range(stream.n_devices) if admitted(i))
     done = 0
 
-    tracer = current_tracer()
     reg = default_registry()
     for lo in range(0, stream.n_devices, chunk_devices):
         hi = min(lo + chunk_devices, stream.n_devices)
-        with tracer.span("engine.chunk", cat="engine", lo=lo, hi=hi):
+        with span("engine.chunk", cat="engine", lo=lo, hi=hi):
             elapsed = stopwatch()
             fallback: List[DeviceOutcome] = []
             by_bucket: Dict[int, List[tuple]] = {}

@@ -27,7 +27,7 @@ import numpy as np
 from repro.agg import build_cell, get_aggregator
 from repro.comm import CommLedger, ModelExchange, StreamExchange
 from repro.comm.wire import agg_extra_wire_nbytes
-from repro.obs.trace import current_tracer, stopwatch
+from repro.obs.trace import span, stopwatch
 from repro.core.selection import ReportColumns
 from repro.distill import DistillConfig, distill_round
 from repro.sim.engine import (
@@ -157,9 +157,8 @@ def run_population(
         )
     ds = federation.dataset
 
-    tracer = current_tracer()
-    with tracer.span("round.train", cat="round", engine=cfg.engine,
-                     devices=ds.n_devices):
+    with span("round.train", cat="round", engine=cfg.engine,
+              devices=ds.n_devices):
         pop = train_population(
             ds, on_update=on_update, lam=cfg.lam, seed=cfg.seed,
             mode=cfg.engine, available=federation.available,
@@ -173,7 +172,7 @@ def run_population(
 
     # --- communication: wire codec + typed byte ledger (repro.comm);
     # only devices that showed up report metadata ---
-    with tracer.span("round.encode", cat="round", codec=cfg.codec):
+    with span("round.encode", cat="round", codec=cfg.codec):
         ex = ModelExchange({o.device_id: o.model for o in outcomes}, reports,
                            codec=cfg.codec, budget_bytes=cfg.budget_bytes)
     ledger = CommLedger()
@@ -197,7 +196,8 @@ def run_population(
              for i in eval_ids),
             chunk=cfg.eval_chunk,
         )
-        return ga.mean()
+        with span("eval.auc"):
+            return ga.mean()
 
     # server aggregation strategy (repro.agg); extras are computed from
     # the by-id outcomes and recorded per cell next to the uploads
@@ -212,7 +212,7 @@ def run_population(
     for strat in cfg.strategies:
         ensemble_auc[strat] = {}
         time_to_aggregate[strat] = {}
-        with tracer.span("round.select", cat="round", strategy=strat):
+        with span("round.select", cat="round", strategy=strat):
             for k in cfg.ks:
                 ids = ex.pick(strat, k, cfg.seed)
                 if not ids:
@@ -329,11 +329,9 @@ def _run_streamed(
     n_val_l: list = []
     local_auc_l: list = []
 
-    tracer = current_tracer()
     elapsed = stopwatch()
-    with tracer.span("round.train", cat="round", engine="streamed",
-                     devices=stream.n_devices,
-                     chunk_devices=cfg.chunk_devices):
+    with span("round.train", cat="round", engine="streamed",
+              devices=stream.n_devices, chunk_devices=cfg.chunk_devices):
         for update in iter_population(
             stream, lam=cfg.lam, seed=cfg.seed, mode="streamed",
             shards=cfg.mesh_shards, chunk_devices=cfg.chunk_devices,
@@ -386,7 +384,7 @@ def _run_streamed(
         _regenerate(want)
         return regen
 
-    with tracer.span("round.encode", cat="round", codec=cfg.codec):
+    with span("round.encode", cat="round", codec=cfg.codec):
         ex = StreamExchange(cols, provider, dim=stream.dim, codec=cfg.codec,
                             budget_bytes=cfg.budget_bytes)
     ledger = CommLedger(compact=True)
@@ -423,7 +421,8 @@ def _run_streamed(
              for i in eval_ids),
             chunk=cfg.eval_chunk,
         )
-        return ga.mean()
+        with span("eval.auc"):
+            return ga.mean()
 
     channel = stream.channel
     ensemble_auc: Dict[str, Dict[int, float]] = {}
@@ -432,7 +431,7 @@ def _run_streamed(
     for strat in cfg.strategies:
         ensemble_auc[strat] = {}
         time_to_aggregate[strat] = {}
-        with tracer.span("round.select", cat="round", strategy=strat):
+        with span("round.select", cat="round", strategy=strat):
             for k in cfg.ks:
                 ids = ex.pick(strat, k, cfg.seed)
                 if not ids:

@@ -217,7 +217,14 @@ def streaming_grouped_auc(
     blocks (the final partial block pads to a power of two), so kernel
     utilization matches the materializing path it replaces while peak
     host memory stays O(chunk), independent of population size.
+
+    Each block is two spans: ``eval.score`` (concat, padding, the
+    scoring call through its host copy; an ensemble packed lazily packs
+    inside its first block's) and ``eval.fold`` (the accumulator
+    updates).
     """
+    from repro.obs.trace import span  # deferred: obs imports utils
+
     acc = GroupedAUC() if acc is None else acc
     parts: list = []   # feature slices (views) of the block being built
     segs: list = []    # (group, label-slice) per part
@@ -227,12 +234,14 @@ def streaming_grouped_auc(
         nonlocal parts, segs, filled
         if not filled:
             return
-        x = np.concatenate(parts).astype(np.float32, copy=False)
-        scores = np.asarray(score_fn(_pad_pow2_rows(x)))[: len(x)]
-        off = 0
-        for group, y in segs:
-            acc.update(group, y, scores[off : off + len(y)])
-            off += len(y)
+        with span("eval.score"):
+            x = np.concatenate(parts).astype(np.float32, copy=False)
+            scores = np.asarray(score_fn(_pad_pow2_rows(x)))[: len(x)]
+        with span("eval.fold"):
+            off = 0
+            for group, y in segs:
+                acc.update(group, y, scores[off : off + len(y)])
+                off += len(y)
         parts, segs, filled = [], [], 0
 
     for group, x, y in groups:

@@ -8,11 +8,17 @@
     chunk spans, with monotonically nested begin/end events;
   * a seeded fleet run's trace is byte-identical across two runs (the
     simulated-ms clock regime — no wall-clock reads anywhere);
-  * kernel spans carry the achieved-vs-roofline FLOPs/bytes attributes
-    from XLA cost analysis;
+  * kernel dispatch carries no hook: an installed tracer leaves it and
+    its result untouched (kernel time comes from the device trace);
+  * ``repro.obs.span`` reaches a duck-typed tracer whose ``enabled`` is
+    False, nests the serve flush and the round's evaluation, and lands
+    in a ``jax.profiler`` trace's host plane;
+  * ``SchedulerStats.queue_wait_ns`` sums each request's wait on the
+    ``repro.obs`` clock;
   * the metrics registry folds the existing silos (CommLedger,
     FleetMetrics, SchedulerStats) into one schema-versioned envelope.
 """
+import contextlib
 import json
 import math
 
@@ -211,21 +217,20 @@ def test_fleet_untraced_runs_match_traced_metrics():
 # ------------------------------------------------------- kernel spans
 
 def test_kernel_spans_carry_roofline_attrs():
+    """Kernel dispatch has no tracing hook any more: an installed tracer
+    records no ``kernel.*`` event, adds no sync, and the result is the
+    untraced one. The peak sheet a TPU is priced by stays."""
     import jax
     from repro.kernels import ops
 
     x = jax.random.normal(jax.random.PRNGKey(0), (64, 16))
+    out_off = ops.rbf_gram(x, x, 0.5)
     t = Tracer()
     with use_tracer(t):
-        ops.rbf_gram(x, x, 0.5)
-    spans = [e for e in t.events if e["name"] == "kernel.rbf_gram"]
-    assert len(spans) == 1
-    args = spans[0]["args"]
-    assert args["flops"] > 0 and args["bytes_accessed"] > 0
-    assert args["achieved_gflops"] > 0 and args["dur_s"] > 0
-    # no peak sheet describes the CPU, so a CPU span has no roofline share
-    assert args["backend"] == "cpu"
-    assert not {"roofline_frac", "roofline_bound_us", "dominant"} & set(args)
+        out_on = ops.rbf_gram(x, x, 0.5)
+    assert not [e for e in t.events if e["name"].startswith("kernel.")]
+    assert t.events == []
+    np.testing.assert_array_equal(np.asarray(out_off), np.asarray(out_on))
     # a TPU is priced by its device kind; an unknown kind is an error
     from types import SimpleNamespace
     from repro.obs.profile import peak_sheet
@@ -235,11 +240,208 @@ def test_kernel_spans_carry_roofline_attrs():
     assert peak_sheet(SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")) is V5E
     with pytest.raises(ValueError, match="no peak sheet"):
         peak_sheet(SimpleNamespace(platform="tpu", device_kind="TPU v99"))
-    # untouched dispatch result when tracing is off
-    out_off = ops.rbf_gram(x, x, 0.5)
-    with use_tracer(Tracer()):
-        out_on = ops.rbf_gram(x, x, 0.5)
-    np.testing.assert_array_equal(np.asarray(out_off), np.asarray(out_on))
+
+
+# ------------------------------------------------- program spans
+
+class _Recorder:
+    """Duck-typed like the benchmark's span recorder: ``enabled`` is
+    False, yet every span must reach it. Keeps each span's name beside
+    the names open around it."""
+
+    enabled = False
+
+    def __init__(self):
+        self.stack, self.opened = [], []
+
+    @contextlib.contextmanager
+    def span(self, name, cat="app", **attrs):
+        self.opened.append((name, tuple(self.stack)))
+        self.stack.append(name)
+        try:
+            yield
+        finally:
+            self.stack.pop()
+
+    def instant(self, *a, **k):
+        return None
+
+    def complete(self, *a, **k):
+        return None
+
+    def parents(self, name):
+        """The innermost enclosing span of each ``name`` span."""
+        return [outer[-1] if outer else None
+                for n, outer in self.opened if n == name]
+
+    def within(self, name):
+        """The names open around each ``name`` span."""
+        return [set(outer) for n, outer in self.opened if n == name]
+
+
+def _tiny_ensemble(seed=0, k=3, d=8):
+    from repro.core import Ensemble
+    from repro.core.svm import SVMModel
+
+    rng = np.random.default_rng(seed)
+    return Ensemble([
+        SVMModel(support_x=rng.normal(0, 1, (12, d)).astype(np.float32),
+                 coef=rng.normal(0, 0.1, 12).astype(np.float32), gamma=0.3)
+        for _ in range(k)
+    ])
+
+
+def test_span_without_tracer_or_profiler_is_the_null_span():
+    from repro.obs import span
+    from repro.obs.trace import _NULL_SPAN
+
+    assert span("x", cat="c", n=1) is _NULL_SPAN
+
+
+def test_serve_spans_nest_under_disabled_recorder():
+    from repro.serve import EnsembleScorer, ServeConfig
+
+    sched = EnsembleScorer(_tiny_ensemble()).scheduler(
+        ServeConfig(max_batch=8, max_queue=64, buckets=(8,), cache_size=16))
+    rows = np.random.default_rng(1).normal(size=(11, 8)).astype(np.float32)
+    rows[5] = rows[4]                              # an in-flight duplicate
+    rec = _Recorder()
+    with use_tracer(rec):
+        out = sched.run(list(rows))
+    assert out.shape == (11,) and sched.stats.deduped_in_flight == 1
+    assert rec.parents("serve.flush") == [None]
+    assert len(rec.parents("serve.score")) == sched.stats.batches == 2
+    for name in ("serve.assemble", "serve.score", "serve.scatter"):
+        assert rec.parents(name) and set(rec.parents(name)) == {"serve.flush"}
+    for name in ("serve.dispatch", "serve.fetch"):
+        assert rec.parents(name) == ["serve.score"] * 2
+    # tracing off: the same answers
+    again = EnsembleScorer(_tiny_ensemble()).scheduler(
+        ServeConfig(max_batch=8, max_queue=64, buckets=(8,), cache_size=16))
+    np.testing.assert_array_equal(again.run(list(rows)), out)
+
+
+def test_round_evaluation_spans_nest_under_disabled_recorder():
+    from repro.core import run_protocol
+    from repro.sim import make_federation
+
+    ds = make_federation("iid", n_devices=16, seed=0, mean_samples=80).dataset
+    rec = _Recorder()
+    with use_tracer(rec):
+        run_protocol(ds, ks=(2, 4), random_trials=2, distill_proxy=30)
+    names = {n for n, _ in rec.opened}
+    assert {"round.local", "round.student", "engine.pack",
+            "engine.outcomes"} <= names
+    for name in ("round.local", "round.student", "round.select", "round.eval"):
+        assert set(rec.parents(name)) == {None}
+    for name in ("engine.pack", "engine.outcomes"):
+        assert set(rec.parents(name)) == {"engine.group"}
+    for stage in ("round.select", "round.eval"):
+        for name in ("ensemble.pack", "eval.score", "eval.fold", "eval.auc"):
+            assert any(stage in w for w in rec.within(name)), (stage, name)
+    # the student's evaluation is its own stage
+    assert any("round.student" in w for w in rec.within("eval.score"))
+    assert set(rec.parents("eval.fold")) == set(rec.parents("eval.score"))
+    assert set(rec.parents("eval.auc")) <= {"round.ideal", "round.select",
+                                            "round.eval", "round.student"}
+
+
+@pytest.fixture
+def fake_ns(monkeypatch):
+    """A stand-in for the ``repro.obs`` clock, read by the scheduler."""
+    import repro.obs.trace as obs_trace
+
+    clock = {"ns": 0}
+    monkeypatch.setattr(obs_trace, "now_ns", lambda: clock["ns"])
+    return clock
+
+
+def _scheduler(max_batch=8, cache_size=0, on_score=None):
+    from repro.serve import MicroBatchScheduler, ServeConfig
+
+    def score(batch):
+        if on_score is not None:
+            on_score()
+        return batch.sum(axis=1)
+
+    return MicroBatchScheduler(score, ServeConfig(
+        max_batch=max_batch, max_queue=64, buckets=(max_batch,),
+        cache_size=cache_size))
+
+
+def test_queue_wait_ns_sums_each_wait_exactly(fake_ns):
+    s = _scheduler()
+    for t in (0, 10, 25):
+        fake_ns["ns"] = t
+        s.submit(np.full(3, t, np.float32))
+    fake_ns["ns"] = 100
+    s.flush()
+    assert s.stats.queue_wait_ns == 100 + 90 + 75
+    fake_ns["ns"] = 130
+    s.submit(np.ones(3, np.float32))
+    fake_ns["ns"] = 170
+    s.flush()
+    assert s.stats.queue_wait_ns == 265 + 40
+
+
+def test_queue_wait_ns_backlog_over_max_batch_waits_longer(fake_ns):
+    def scoring_takes_50():
+        fake_ns["ns"] += 50
+
+    s = _scheduler(max_batch=2, on_score=scoring_takes_50)
+    s.submit_many([np.full(3, i, np.float32) for i in range(5)])   # at 0
+    fake_ns["ns"] = 100
+    assert s.flush() == 3
+    # batches pop at 100, 150 and 200: the backlog waited longer
+    assert s.stats.queue_wait_ns == 2 * 100 + 2 * 150 + 200
+    assert s.stats.queue_wait_ns > 5 * 100
+
+
+def test_queue_wait_ns_counts_cache_hits_and_duplicates(fake_ns):
+    s = _scheduler(cache_size=8)
+    row = np.arange(3, dtype=np.float32)
+    s.submit(row)
+    s.submit(row)                                  # deduped in flight
+    fake_ns["ns"] = 20
+    s.flush()
+    fake_ns["ns"] = 50
+    t = s.submit(row)                              # answered from the cache
+    fake_ns["ns"] = 80
+    s.flush()
+    st = s.stats
+    assert (st.scored_rows, st.deduped_in_flight, st.answered_from_cache) == (1, 1, 1)
+    assert st.queue_wait_ns == 20 + 20 + 30
+    assert s.result(t) == pytest.approx(3.0)
+
+
+def test_span_lands_on_profiler_host_plane(tmp_path):
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+    from repro.serve import EnsembleScorer, ServeConfig
+
+    sched = EnsembleScorer(_tiny_ensemble()).scheduler(
+        ServeConfig(max_batch=8, buckets=(8,)))
+    rows = list(np.random.default_rng(2).normal(size=(5, 8)).astype(np.float32))
+    sched.run(rows)                                # compiled before the trace
+    rec = _Recorder()
+    with jax.profiler.trace(str(tmp_path)), use_tracer(rec):
+        sched.run(rows)
+    assert rec.parents("serve.flush") == [None]     # the tracer still sees it
+    paths = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True)
+    assert paths
+    host = {}
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    host.setdefault(e.name, []).append(e.duration_ns)
+    for name in ("serve.flush", "serve.assemble", "serve.score",
+                 "serve.dispatch", "serve.fetch", "serve.scatter"):
+        assert name in host, name
+    assert len(host["serve.flush"]) == 1
 
 
 def test_timed_call_times_and_emits_bench_spans():
