@@ -27,12 +27,13 @@ def run():
     for (b, k, n, d) in [(1024, 8, 200, 32), (1024, 32, 200, 32)]:
         ks = jax.random.split(key, 4)
         x = jax.random.normal(ks[0], (b, d))
-        sup = jax.random.normal(ks[1], (k, n, d))
-        coef = jax.random.normal(ks[2], (k, n))
-        gammas = jax.random.uniform(ks[3], (k,), minval=0.1, maxval=1.0)
-        f = jax.jit(ref.ensemble_score_ref)
+        tiles = -(-k * n // 128)  # k members of n rows in 128-row tiles
+        sup = jax.random.normal(ks[1], (tiles, 128, d))
+        coef = jax.random.normal(ks[2], (tiles, 128))
+        gamma = jax.random.uniform(ks[3], (tiles, 128), minval=0.1, maxval=1.0)
+        f = jax.jit(ref.ensemble_score_ref, static_argnames=("k",))
         us = timed_call(f"ensemble_score.b{b}k{k}n{n}d{d}",
-                        lambda: f(x, sup, coef, gammas))
+                        lambda: f(x, sup, coef, gamma, k=k))
         flops = 2 * k * b * n * d
         rows.append(csv_row(f"kernel.ensemble_score.b{b}k{k}n{n}d{d}", f"{us:.1f}",
                             f"us_per_call; {flops / us / 1e3:.2f} GFLOP/s (jnp ref)"))

@@ -111,6 +111,8 @@ def paper_round(scale: float = 1.0, proxy_size: int = 10_000):
 def serving(ds, res, n_requests: int = 4096, k: int = 50):
     """The round's k-member cv ensemble behind the micro-batch
     scheduler, fp32 and int8-wire, checked against the oracles."""
+    from functools import partial
+
     import jax
     import numpy as np
 
@@ -153,8 +155,11 @@ def serving(ds, res, n_requests: int = 4096, k: int = 50):
         dispatches = [e for e in tracer.events
                       if e["name"] == "serve.dispatch" and e["ph"] == "B"]
         st = scorer.stacked
-        packed = ((st.sup, st.coef, st.gammas) if form == "fp32" else
-                  (st.q, st.scale, st.zero, st.coef, st.gammas))
+        if form == "fp32":
+            oracle = partial(oracle, k=st.k)
+            packed = (st.sup, st.coef, st.gamma)
+        else:
+            packed = (st.q, st.scale, st.zero, st.coef, st.gammas)
         want = np.asarray(jax.jit(oracle)(rows, *packed))
         err = float(np.max(np.abs(answers - want)))
         tol = KERNEL_REGISTRY[kernel].tol

@@ -151,8 +151,9 @@ class QuantizedSVM:
 
 @dataclasses.dataclass(frozen=True)
 class QuantizedStackedEnsemble:
-    """Packed homogeneous int8 ensemble — the quantized mirror of
-    ``core.ensemble.StackedEnsemble``. Supports stay int8 end-to-end;
+    """Packed homogeneous int8 ensemble, member-padded to (k, n_max) —
+    the quantized counterpart of ``core.ensemble.StackedEnsemble`` (same
+    ``score``/``k``/``d`` surface). Supports stay int8 end-to-end;
     scoring is one fused ``ensemble_score_q8`` call per query chunk
     (on-the-fly dequant in VMEM, no fp32 support matrix in HBM)."""
 

@@ -116,10 +116,6 @@ class StackedLinear:
         return 1
 
     @property
-    def n_max(self) -> int:
-        return 1
-
-    @property
     def d(self) -> int:
         return int(self.w.shape[0])
 

@@ -5,16 +5,19 @@ Two representations:
     ensembles are packed once into a ``StackedEnsemble`` and scored with
     the fused ``ensemble_score`` kernel; mixed ensembles fall back to
     the per-member mean.
-  * ``StackedEnsemble`` — homogeneous padded arrays stacked on a leading
-    member axis: supports (k, n_max, d), dual coefs (k, n_max), gammas
-    (k,). This is the serve-path representation: one jit'd fused call
-    per query chunk (``repro.kernels.ops.ensemble_score``), no
-    (k, batch, n_max) Gram tensor in HBM, shardable over the mesh
-    'data' axis on the member dim.
+  * ``StackedEnsemble`` — every member's support rows concatenated in
+    member order and cut into row tiles: supports (T, bn, d), dual
+    coefs (T, bn), and a per-row bandwidth (T, bn) that repeats each
+    member's gamma over its own rows. Only the total is padded, up to a
+    whole tile, so the kernel reads each member's real supports and not
+    k x n_max padded ones. This is the serve-path representation: one
+    jit'd fused call per query chunk
+    (``repro.kernels.ops.ensemble_score``), no (k, batch, n) Gram tensor
+    in HBM.
 
-``Ensemble.predict_padded`` keeps the pre-fusion path (pack per call +
-vmap'd padded Gram) as the benchmark baseline for
-``benchmarks/serve_bench.py``.
+``Ensemble.predict_padded`` keeps the pre-fusion path (member-padded
+arrays built per call + vmap'd padded Gram) as the benchmark baseline
+for ``benchmarks/serve_bench.py``.
 """
 from __future__ import annotations
 
@@ -53,21 +56,25 @@ def chunked_bucket_predict(score_fn, x: np.ndarray, chunk: int) -> np.ndarray:
     return np.concatenate(outs)
 
 
+SUPPORT_TILE = 128  # support rows per kernel tile
+
+
 @dataclasses.dataclass(frozen=True)
 class StackedEnsemble:
-    """Packed homogeneous ensemble: the fused serving representation."""
+    """Packed SVM ensemble in support row tiles: the fused serving
+    representation.
 
-    sup: jnp.ndarray     # (k, n_max, d) zero-padded support vectors
-    coef: jnp.ndarray    # (k, n_max) zero-padded dual coefficients
-    gammas: jnp.ndarray  # (k,) per-member RBF bandwidths
+    Row r of the flattened (T * bn) rows is a support of one member, in
+    member order; ``gamma`` repeats that member's bandwidth on each of
+    its rows. The tail past the last real row is zero in all three
+    arrays, so it adds nothing to a score. ``bn`` is ``SUPPORT_TILE``,
+    or the total rounded up to a multiple of 8 when that is smaller.
+    """
 
-    @property
-    def k(self) -> int:
-        return self.sup.shape[0]
-
-    @property
-    def n_max(self) -> int:
-        return self.sup.shape[1]
+    sup: jnp.ndarray    # (T, bn, d) support rows of every member
+    coef: jnp.ndarray   # (T, bn) dual coefficients, zero on the tail
+    gamma: jnp.ndarray  # (T, bn) each row's member bandwidth, zero on the tail
+    k: int              # member count: the score is the row sum over k
 
     @property
     def d(self) -> int:
@@ -83,25 +90,28 @@ class StackedEnsemble:
                     f"StackedEnsemble requires SVMModel members, got {type(m).__name__}; "
                     "use ensemble_predict_mean for mixed ensembles"
                 )
-        with span("ensemble.pack"):
-            n_max = max(len(m.coef) for m in members)
+        counts = np.array([len(m.coef) for m in members])
+        rows = int(counts.sum())
+        bn = min(SUPPORT_TILE, max(-(-rows // 8) * 8, 8))
+        tiles = max(-(-rows // bn), 1)
+        with span("ensemble.pack", rows=rows, packed_rows=tiles * bn):
             d = members[0].support_x.shape[1]
-            k = len(members)
-            sup = np.zeros((k, n_max, d), np.float32)
-            coef = np.zeros((k, n_max), np.float32)
-            gammas = np.zeros((k,), np.float32)
-            for i, m in enumerate(members):
-                n = len(m.coef)
-                sup[i, :n] = m.support_x
-                coef[i, :n] = m.coef
-                gammas[i] = m.gamma
-            return cls(jnp.asarray(sup), jnp.asarray(coef), jnp.asarray(gammas))
+            sup = np.zeros((tiles * bn, d), np.float32)
+            coef = np.zeros(tiles * bn, np.float32)
+            gamma = np.zeros(tiles * bn, np.float32)
+            np.concatenate([m.support_x for m in members], out=sup[:rows])
+            np.concatenate([m.coef for m in members], out=coef[:rows])
+            gamma[:rows] = np.repeat(np.array([m.gamma for m in members], np.float32), counts)
+            return cls(jnp.asarray(sup.reshape(tiles, bn, d)),
+                       jnp.asarray(coef.reshape(tiles, bn)),
+                       jnp.asarray(gamma.reshape(tiles, bn)), len(members))
 
     def score(self, x) -> jnp.ndarray:
         """Fused mean member score for one query block. x: (b, d) -> (b,)."""
         from repro.kernels import ops as kops
 
-        return kops.ensemble_score(jnp.asarray(x, jnp.float32), self.sup, self.coef, self.gammas)
+        return kops.ensemble_score(jnp.asarray(x, jnp.float32), self.sup, self.coef,
+                                   self.gamma, self.k)
 
     def predict(self, x: np.ndarray, chunk: int = 4096) -> np.ndarray:
         """Chunked scoring with power-of-two bucket padding (see
@@ -162,12 +172,18 @@ class Ensemble:
         """Pre-fusion baseline: pack per call, vmap a full padded Gram.
 
         Kept (not routed anywhere) as the comparison point for
-        ``benchmarks/serve_bench.py``: it re-packs the (k, n_max, d)
-        support tensor on every call and materializes the whole
-        (k, chunk, n_max) Gram before reducing it.
+        ``benchmarks/serve_bench.py``: it builds a (k, n_max, d)
+        member-padded support tensor on every call and materializes the
+        whole (k, chunk, n_max) Gram before reducing it.
         """
-        packed = StackedEnsemble.from_members(self.members)  # per call, on purpose
-        sup_j, coef_j, gam_j = packed.sup, packed.coef, packed.gammas
+        n_max = max(len(m.coef) for m in self.members)
+        sup = np.zeros((self.k, n_max, self.members[0].support_x.shape[1]), np.float32)
+        coef = np.zeros((self.k, n_max), np.float32)
+        for i, m in enumerate(self.members):  # per call, on purpose
+            sup[i, : len(m.coef)] = m.support_x
+            coef[i, : len(m.coef)] = m.coef
+        sup_j, coef_j = jnp.asarray(sup), jnp.asarray(coef)
+        gam_j = jnp.asarray([m.gamma for m in self.members], jnp.float32)
 
         def member_scores(s, c, g, xq):
             # zero-padded support rows contribute exp(-g*dist)*0 via coef

@@ -149,28 +149,31 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0):
     return _flash_ref(q, k, v, causal, window)
 
 
-@jax.jit
-def _ens_tpu(x, sup, coef, gammas):
-    return ensemble_score_pallas(x, sup, coef, gammas)
+@partial(jax.jit, static_argnames=("k",))
+def _ens_tpu(x, sup, coef, gamma, k):
+    return ensemble_score_pallas(x, sup, coef, gamma, k)
 
 
-@jax.jit
-def _ens_ref(x, sup, coef, gammas):
-    return ref.ensemble_score_ref(x, sup, coef, gammas)
+@partial(jax.jit, static_argnames=("k",))
+def _ens_ref(x, sup, coef, gamma, k):
+    return ref.ensemble_score_ref(x, sup, coef, gamma, k)
 
 
-def ensemble_score(x, sup, coef, gammas):
+def ensemble_score(x, sup, coef, gamma, k: int):
     """Fused mean-of-member RBF-SVM scoring (the repro.serve hot path).
 
-    x: (b, d); sup: (k, n_max, d); coef: (k, n_max); gammas: (k,).
-    Returns (b,) fp32. The Pallas path never materializes the
-    (k, b, n_max) Gram tensor in HBM.
+    x: (b, d); sup: (T, bn, d) row tiles of every member's supports;
+    coef, gamma: (T, bn) dual coefs and per-row bandwidths (zero on the
+    tail); k: member count. Returns (b,) fp32. The Pallas path walks
+    the real support tiles only and never materializes a Gram tensor
+    in HBM.
     """
+    k = int(k)
     if _on_tpu():
-        return _ens_tpu(x, sup, coef, gammas)
+        return _ens_tpu(x, sup, coef, gamma, k)
     if _force_interpret():
-        return ensemble_score_pallas(x, sup, coef, gammas, interpret=True)
-    return _ens_ref(x, sup, coef, gammas)
+        return ensemble_score_pallas(x, sup, coef, gamma, k, interpret=True)
+    return _ens_ref(x, sup, coef, gamma, k)
 
 
 @jax.jit
@@ -271,10 +274,18 @@ def _mk_flash_attention(rng):
 
 
 def _mk_ensemble_score(rng):
-    return (rng.normal(size=(40, 12)).astype(np.float32),
-            rng.normal(size=(3, 48, 12)).astype(np.float32),
-            (rng.normal(size=(3, 48)) / 48).astype(np.float32),
-            rng.uniform(0.1, 1.0, size=3).astype(np.float32))
+    # four members of 20, 9, 14 and 3 rows in 8-row tiles: members
+    # straddle tiles, tiles mix gammas, and the tail is zero
+    lengths = (20, 9, 14, 3)
+    rows = sum(lengths)
+    sup = np.zeros((6 * 8, 12), np.float32)
+    coef = np.zeros(6 * 8, np.float32)
+    sup[:rows] = rng.normal(size=(rows, 12))
+    coef[:rows] = rng.normal(size=rows) / 16
+    gamma = np.zeros(6 * 8, np.float32)
+    gamma[:rows] = np.repeat(rng.uniform(0.1, 1.0, size=4), lengths)
+    return (rng.normal(size=(40, 12)).astype(np.float32), sup.reshape(6, 8, 12),
+            coef.reshape(6, 8), gamma.reshape(6, 8), len(lengths))
 
 
 def _mk_ensemble_score_q8(rng):

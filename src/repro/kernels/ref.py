@@ -11,8 +11,9 @@ NEG_INF = -1e9
 _F32 = jax.lax.Precision.HIGHEST
 
 
-def rbf_gram_ref(x1, x2, gamma: float):
-    """exp(-gamma ||x1_i - x2_j||^2). x1: (m, d), x2: (n, d) -> (m, n)."""
+def rbf_gram_ref(x1, x2, gamma):
+    """exp(-gamma ||x1_i - x2_j||^2). x1: (m, d), x2: (n, d) -> (m, n);
+    gamma is one bandwidth, or (n,) bandwidths, one for each x2 row."""
     x1 = x1.astype(jnp.float32)
     x2 = x2.astype(jnp.float32)
     sq1 = jnp.sum(x1 * x1, axis=1)[:, None]
@@ -66,37 +67,40 @@ def rbf_gram_q8_ref(x, q, scale, zero, gamma: float):
     return rbf_gram_ref(x, s, gamma)
 
 
-def ensemble_score_ref(x, sup, coef, gammas):
-    """Mean of member RBF-SVM decision scores (oracle for ensemble_score).
+def ensemble_score_ref(x, sup, coef, gamma, k: int):
+    """Mean of member RBF-SVM decision scores over row-tiled supports
+    (oracle for ensemble_score): sum_rows coef exp(-gamma_row ||x - s||^2) / k.
 
-    x: (b, d); sup: (k, n_max, d); coef: (k, n_max); gammas: (k,).
-    Returns (b,). Zero-padded support rows contribute nothing because
-    their coefficients are zero.
+    x: (b, d); sup: (T, bn, d) every member's support rows; coef,
+    gamma: (T, bn) dual coefs and per-row bandwidths, zero on the tail;
+    k: the member count. Returns (b,).
     """
-    x = x.astype(jnp.float32)
-
-    def member_scores(s, c, g):
-        return jnp.dot(rbf_gram_ref(x, s, g), c, precision=_F32)
-
-    scores = jax.vmap(member_scores)(
-        sup.astype(jnp.float32), coef.astype(jnp.float32), gammas.astype(jnp.float32)
-    )  # (k, b)
-    return jnp.mean(scores, axis=0)
+    gram = rbf_gram_ref(x, sup.reshape(-1, sup.shape[-1]), gamma.reshape(-1).astype(jnp.float32))
+    return jnp.dot(gram, coef.reshape(-1).astype(jnp.float32), precision=_F32) / k
 
 
 def ensemble_score_q8_ref(x, q, scale, zero, coef, gammas):
     """Mean of member scores from int8 affine-quantized supports
     (oracle for ensemble_score_q8): dequantize per member, then the
-    fp32 ensemble oracle.
+    mean of the members' fp32 scores.
 
     x: (b, d); q: (k, n_max, d) int8; scale, zero: (k, d); coef:
-    (k, n_max); gammas: (k,). Returns (b,).
+    (k, n_max); gammas: (k,). Returns (b,). Zero-padded support rows
+    contribute nothing because their coefficients are zero.
     """
+    x = x.astype(jnp.float32)
     sup = (
         q.astype(jnp.float32) * scale.astype(jnp.float32)[:, None, :]
         + zero.astype(jnp.float32)[:, None, :]
     )
-    return ensemble_score_ref(x, sup, coef, gammas)
+
+    def member_scores(s, c, g):
+        return jnp.dot(rbf_gram_ref(x, s, g), c, precision=_F32)
+
+    scores = jax.vmap(member_scores)(
+        sup, coef.astype(jnp.float32), gammas.astype(jnp.float32)
+    )  # (k, b)
+    return jnp.mean(scores, axis=0)
 
 
 def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = 0):

@@ -99,6 +99,65 @@ def test_ensemble_predict_equals_mean_of_members(rng):
     np.testing.assert_allclose(got, want, atol=1e-4)
 
 
+def _ragged_members(lengths, d=5, seed=0):
+    from repro.core.svm import SVMModel
+
+    rng = np.random.default_rng(seed)
+    return [SVMModel(support_x=rng.normal(0, 1, (n, d)).astype(np.float32),
+                     coef=rng.normal(0, 0.1, n).astype(np.float32),
+                     gamma=float(rng.uniform(0.05, 0.5)))
+            for n in lengths]
+
+
+@pytest.mark.parametrize("lengths", [(100, 70, 130, 5), (3, 9, 1), (128,), (64, 64, 1)])
+def test_stacked_pack_keeps_member_rows_in_order(lengths):
+    """Row tiles hold each member's supports and coefs in member order,
+    repeat its gamma over exactly its own rows, and are zero past the
+    last real row."""
+    from repro.core.ensemble import StackedEnsemble
+
+    members = _ragged_members(lengths)
+    st = StackedEnsemble.from_members(members)
+    sup = np.asarray(st.sup).reshape(-1, 5)
+    coef = np.asarray(st.coef).reshape(-1)
+    gamma = np.asarray(st.gamma).reshape(-1)
+    assert st.k == len(lengths) and st.d == 5
+    start = 0
+    for m in members:
+        stop = start + len(m.coef)
+        np.testing.assert_array_equal(sup[start:stop], m.support_x)
+        np.testing.assert_array_equal(coef[start:stop], m.coef)
+        np.testing.assert_array_equal(gamma[start:stop], np.float32(m.gamma))
+        start = stop
+    assert start == sum(lengths)
+    assert not sup[start:].any() and not coef[start:].any() and not gamma[start:].any()
+    assert len(coef) - start < st.sup.shape[1]  # the tail is under one tile
+
+
+def test_pack_span_counts_real_and_padded_rows():
+    from repro.core.ensemble import StackedEnsemble
+    from repro.obs import Tracer, use_tracer
+
+    tracer = Tracer()
+    with use_tracer(tracer):
+        StackedEnsemble.from_members(_ragged_members((100, 70, 130, 5)))
+        StackedEnsemble.from_members(_ragged_members((10, 20)))
+    begins = [e["args"] for e in tracer.events
+              if e["name"] == "ensemble.pack" and e["ph"] == "B"]
+    assert begins == [{"rows": 305, "packed_rows": 384}, {"rows": 30, "packed_rows": 32}]
+
+
+def test_ensemble_predict_mixed_sizes_equals_mean(rng):
+    """Members of very different sizes (one tile shared by several, one
+    member over several tiles) score as the plain mean of members."""
+    members = _ragged_members((7, 300, 40, 1, 129, 12), d=4, seed=1)
+    ens = Ensemble(members)
+    xq = rng.normal(0, 1, (70, 4)).astype(np.float32)
+    want = ensemble_predict_mean(members, xq)
+    np.testing.assert_allclose(ens.predict(xq), want, atol=1e-5)
+    np.testing.assert_allclose(ens.predict_padded(xq), want, atol=1e-5)
+
+
 def test_ensemble_beats_worst_member(rng):
     xs, ys = _blob_data(rng, n=400)
     members = []

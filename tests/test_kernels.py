@@ -82,7 +82,7 @@ def test_registry_shard_specs_preserve_dispatch(name):
     mesh = make_sim_mesh(4)
     in_specs, out_specs = spec.shard_specs(mesh)
     arrays = [a for a in args if hasattr(a, "shape")]
-    statics = args[len(arrays):]  # trailing python scalars (gamma)
+    statics = args[len(arrays):]  # trailing python scalars (gamma, k)
     fn = jax.shard_map(lambda *xs: spec.dispatch(*xs, *statics), mesh=mesh,
                    in_specs=in_specs[: len(arrays)], out_specs=out_specs)
     np.testing.assert_allclose(
@@ -223,25 +223,70 @@ def test_batched_rbf_gram_matches_per_device_unbatched(key):
         np.testing.assert_allclose(np.asarray(out[t]), np.asarray(want), atol=1e-5)
 
 
+def _svm_members(rng, lengths, d):
+    """Random RBF-SVM members with the given support counts and a
+    bandwidth each."""
+    from repro.core.svm import SVMModel
+
+    return [SVMModel(support_x=rng.normal(size=(n, d)).astype(np.float32),
+                     coef=(rng.normal(size=n) / max(n, 1)).astype(np.float32),
+                     gamma=float(rng.uniform(0.1, 2.0)))
+            for n in lengths]
+
+
+def _padded_mean(x, members):
+    """The member-padded definition: mean_t sum_j coef_tj exp(-gamma_t ||x - s_tj||^2)."""
+    return jnp.stack([ref.rbf_gram_ref(x, m.support_x, m.gamma) @ m.coef
+                      for m in members]).mean(axis=0)
+
+
+def _check_row_tiled_score(x, members, block_b=128):
+    """Pack ``members`` into row tiles; the kernel must match both the
+    row-layout oracle and the member-padded mean."""
+    from repro.core.ensemble import StackedEnsemble
+
+    st = StackedEnsemble.from_members(members)
+    out = ensemble_score_pallas(x, st.sup, st.coef, st.gamma, st.k,
+                                block_b=block_b, interpret=True)
+    want = ref.ensemble_score_ref(x, st.sup, st.coef, st.gamma, st.k)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_padded_mean(x, members)),
+                               atol=1e-4)
+    assert out.shape == (x.shape[0],)
+    return st
+
+
 @pytest.mark.parametrize(
     "b,k,n_max,d", [(7, 1, 5, 3), (64, 8, 100, 16), (130, 5, 33, 4), (1, 12, 200, 64), (33, 3, 130, 8)]
 )
 def test_ensemble_score_sweep(key, b, k, n_max, d):
-    """Fused serve kernel vs oracle, with ragged zero-padded supports."""
-    ks = jax.random.split(key, 4)
-    x = jax.random.normal(ks[0], (b, d))
-    sup = jax.random.normal(ks[1], (k, n_max, d))
-    coef = jax.random.normal(ks[2], (k, n_max))
-    gammas = jax.random.uniform(ks[3], (k,), minval=0.1, maxval=2.0)
-    # ragged members: zero out per-member tails as the packer does
+    """Fused serve kernel vs oracle, ragged members packed into row tiles."""
+    x = jax.random.normal(key, (b, d))
     lengths = np.random.default_rng(0).integers(1, n_max + 1, size=k)
-    mask = np.arange(n_max)[None, :] < lengths[:, None]
-    sup = sup * mask[:, :, None]
-    coef = coef * mask
-    out = ensemble_score_pallas(x, sup, coef, gammas, block_b=64, block_n=64, interpret=True)
-    want = ref.ensemble_score_ref(x, sup, coef, gammas)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4)
-    assert out.shape == (b,)
+    members = _svm_members(np.random.default_rng(derive_device_seed(b, k)), lengths, d)
+    _check_row_tiled_score(x, members, block_b=64)
+
+
+@pytest.mark.parametrize("lengths", [
+    (100, 70, 130, 5),      # members straddle the 128-row tile boundaries
+    (3, 5, 2, 7, 4, 9),     # six gammas inside one tile
+    (200,),                 # k = 1 over two tiles
+    (1,),                   # k = 1, one row
+    (10, 20),               # 30 rows: one 32-row tile, below 128
+    (128, 1),               # a zero tail of 127 rows
+    (64, 64, 128),          # whole tiles, no tail
+], ids=["straddle", "gammas_in_tile", "k1", "k1_one_row", "below_tile",
+        "zero_tail", "no_tail"])
+def test_ensemble_score_row_tiles(key, lengths):
+    """Row-tile layout edge cases against the row oracle and the
+    member-padded mean."""
+    d = 6
+    x = jax.random.normal(key, (37, d))
+    members = _svm_members(np.random.default_rng(len(lengths)), lengths, d)
+    st = _check_row_tiled_score(x, members)
+    rows = sum(lengths)
+    tiles, bn, _ = st.sup.shape
+    assert bn == min(128, -(-rows // 8) * 8) and tiles == -(-rows // bn)
 
 
 @pytest.mark.parametrize(
@@ -269,6 +314,9 @@ def test_ensemble_score_q8_sweep(key, b, k, n_max, d):
 def test_ensemble_score_q8_matches_fp32_kernel_on_dequantized(key):
     """q8 ensemble kernel == fp32 ensemble kernel fed the materialized
     dequantized supports (layout change, not a numerics change)."""
+    from repro.core.ensemble import StackedEnsemble
+    from repro.core.svm import SVMModel
+
     rng = np.random.default_rng(3)
     b, k, n_max, d = 40, 3, 50, 8
     x = jax.random.normal(key, (b, d))
@@ -281,21 +329,22 @@ def test_ensemble_score_q8_matches_fp32_kernel_on_dequantized(key):
     out = ensemble_score_q8_pallas(x, jnp.asarray(q), jnp.asarray(scale),
                                    jnp.asarray(zero), jnp.asarray(coef),
                                    jnp.asarray(gammas), interpret=True)
-    want = ensemble_score_pallas(x, jnp.asarray(sup), jnp.asarray(coef),
-                                 jnp.asarray(gammas), interpret=True)
+    st = StackedEnsemble.from_members(
+        [SVMModel(sup[t], coef[t], float(gammas[t])) for t in range(k)])
+    want = ensemble_score_pallas(x, st.sup, st.coef, st.gamma, st.k, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4)
 
 
 def test_ensemble_score_matches_explicit_mean(key):
-    """Fused result == mean over per-member padded-gram scores."""
+    """Fused result == mean over per-member Gram scores."""
     b, k, n_max, d = 40, 6, 50, 8
-    ks = jax.random.split(key, 4)
-    x = jax.random.normal(ks[0], (b, d))
-    sup = jax.random.normal(ks[1], (k, n_max, d))
-    coef = jax.random.normal(ks[2], (k, n_max))
-    gammas = jax.random.uniform(ks[3], (k,), minval=0.2, maxval=1.0)
-    out = ensemble_score_pallas(x, sup, coef, gammas, interpret=True)
-    member = [ref.rbf_gram_ref(x, sup[t], float(gammas[t])) @ coef[t] for t in range(k)]
+    x = jax.random.normal(key, (b, d))
+    members = _svm_members(np.random.default_rng(6), [n_max] * k, d)
+    from repro.core.ensemble import StackedEnsemble
+
+    st = StackedEnsemble.from_members(members)
+    out = ensemble_score_pallas(x, st.sup, st.coef, st.gamma, st.k, interpret=True)
+    member = [ref.rbf_gram_ref(x, m.support_x, m.gamma) @ m.coef for m in members]
     want = jnp.stack(member).mean(axis=0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4)
 

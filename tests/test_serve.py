@@ -107,9 +107,9 @@ def test_predict_buckets_chunk_shapes(monkeypatch, rng):
     seen = []
     real = kops.ensemble_score
 
-    def spy(x, sup, coef, gammas):
+    def spy(x, sup, coef, gamma, k):
         seen.append(x.shape[0])
-        return real(x, sup, coef, gammas)
+        return real(x, sup, coef, gamma, k)
 
     monkeypatch.setattr(kops, "ensemble_score", spy)
     x, y = _blob_data(np.random.default_rng(0))

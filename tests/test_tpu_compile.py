@@ -5,7 +5,9 @@ compiler refuses: blocks whose last two dims break the (8, 128) tiling
 rule, or more fast memory than a kernel may use. These tests hand each
 ``KERNEL_REGISTRY`` kernel's ``pallas_fn`` to the TPU compiler for one
 chip of a described ``v5e:2x2`` topology (no chip attached) and assert
-that the kernel survives as a ``tpu_custom_call``.
+that the kernel survives as a ``tpu_custom_call``. The benchmark's
+count of ``ensemble_score``'s work, read from that custom call's
+operand shapes, is checked on the compiled jit wrapper too.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
@@ -32,8 +34,8 @@ SHAPES = {
                     {"gamma": 0.5}),
     "batched_rbf_gram": ([((64, 512, 64), F32), ((64, 512, 64), F32), ((64,), F32)], {}),
     "flash_attention": ([((4, 1024, 8, 128), F32)] * 3, {}),
-    "ensemble_score": ([((256, 64), F32), ((32, 512, 64), F32), ((32, 512), F32),
-                        ((32,), F32)], {}),
+    "ensemble_score": ([((256, 64), F32), ((40, 128, 64), F32), ((40, 128), F32),
+                        ((40, 128), F32)], {"k": 50}),
     "ensemble_score_q8": ([((256, 64), F32), ((32, 512, 64), I8), ((32, 64), F32),
                            ((32, 64), F32), ((32, 512), F32), ((32,), F32)], {}),
 }
@@ -71,3 +73,28 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     fn = KERNEL_REGISTRY[name].pallas_fn
     compiled = jax.jit(lambda *a: fn(*a, **static)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ensemble_score_call_counts_its_row_tiles(one_chip):
+    """The round's roofline reader (``bench.counting.cost_of``) counts an
+    ``ensemble_score`` call from the operand shapes of the jitted
+    wrapper's custom call: (b, d) queries, then (T, bn, d) support
+    tiles. Compile that wrapper for the chip and count its HLO: the
+    count must be the 2 b T bn (d + 1) the kernel does."""
+    from jax._src.lib import _jax
+
+    from bench.counting import cost_of
+    from repro.kernels import ops
+
+    b, tiles, bn, d = 256, 40, 128, 784
+    args = [jax.ShapeDtypeStruct(s, F32, sharding=one_chip)
+            for s in ((b, d), (tiles, bn, d), (tiles, bn), (tiles, bn))]
+    compiled = ops._ens_tpu.lower(*args, k=50).compile()
+    options = _jax.HloPrintOptions()
+    options.print_operand_shape = True
+    lines = [line.strip()
+             for module in compiled.runtime_executable().hlo_modules()
+             for line in module.to_string(options).splitlines()]
+    counts = [c for c in (cost_of("ensemble_score", line) for line in lines) if c]
+    assert len(counts) == 1
+    assert counts[0][0] == 2 * b * tiles * bn * (d + 1)
